@@ -527,68 +527,81 @@ def mapper_stream(n_reads=24, read_len=400, genome_len=200_000, decoys=4,
     return rows, derived
 
 
+def sharded_vs_single(mesh_devices, n_reads, read_len, seed, backend):
+    """Wall time per align call and transfer counts for the single-device
+    aligner vs the same aligner sharded over a ('data',) mesh of
+    `mesh_devices` devices (GenASMAligner(mesh=...) — the shard_map'd
+    Pallas dispatch / GSPMD jnp path of kernels.ops).  Runs in the calling
+    process, on whatever devices JAX sees."""
+    from repro.launch.mesh import make_test_mesh
+
+    g = synth_genome(200_000, seed=seed)
+    rs = simulate_reads(g, n_reads, ReadSimConfig(
+        read_len=read_len, error_rate=0.10, seed=seed + 1))
+    cfg = AlignerConfig(W=64, O=24, k=12, backend=backend)
+    rep = {}
+    for name, mesh in (("1dev", None),
+                       (f"{mesh_devices}dev",
+                        make_test_mesh((mesh_devices,), ("data",)))):
+        al = GenASMAligner(cfg, rescue_rounds=1, mesh=mesh)
+        al.align(rs.reads, rs.ref_segments)          # warm / compile
+        transfer.reset()
+        ts = []
+        for _ in range(3):
+            t0 = time.time()
+            al.align(rs.reads, rs.ref_segments)
+            ts.append(time.time() - t0)
+        st = transfer.stats()
+        rep[name] = {"wall_s": sorted(ts)[1], "h2d_bytes": st.h2d_bytes // 3,
+                     "d2h_bytes": st.d2h_bytes // 3,
+                     "h2d_calls": st.h2d_calls // 3,
+                     "d2h_calls": st.d2h_calls // 3}
+    return rep
+
+
 def multidevice(n_devices=8, n_reads=32, read_len=240, seed=5,
                 backend="jnp"):
-    """Sharded-vs-single throughput on `n_devices` forced host devices.
+    """Sharded-vs-single throughput (see `sharded_vs_single`).
 
-    The device count must be fixed before jax imports, so this re-execs a
-    fresh interpreter with XLA_FLAGS=--xla_force_host_platform_device_count
-    and parses a JSON report: wall time per align call, pairs/s (total and
-    per device) and host<->device transfer bytes for the single-device run
-    vs the mesh-sharded run (GenASMAligner(mesh=...) — the shard_map'd
-    Pallas dispatch / GSPMD jnp path of kernels.ops).  On this CPU
-    container the mesh is emulated (no parallel speedup is expected — the
-    number that matters is per-device pairs/s and unchanged transfer
-    counts); on real hardware the same code path is the scaling claim."""
+    On an accelerator the comparison runs in this process over every
+    device JAX sees (`n_devices` is ignored): a chip belongs to one
+    process, so a child could not reach it.  On the CPU the device count
+    must be fixed before jax imports, so this re-execs a fresh interpreter
+    with XLA_FLAGS=--xla_force_host_platform_device_count=`n_devices` and
+    parses its JSON report; that mesh is emulated (no parallel speedup is
+    expected — the numbers that matter there are per-device pairs/s and
+    unchanged transfer counts)."""
     import json as _json
     import os
     import subprocess
     import sys
+
+    on_cpu = jax.default_backend() == "cpu"
+    if not on_cpu:
+        n_devices = len(jax.devices())
     # the jnp path's GSPMD constraint (and equal sharding generally) needs
     # the batch to divide the device count — quantise so the sharded row
     # can never silently benchmark an unsharded run
     n_reads = -(-n_reads // n_devices) * n_devices
-    script = f"""
-import json, time
-import numpy as np
-from repro.core.aligner import GenASMAligner
-from repro.core.config import AlignerConfig
-from repro.core import transfer
-from repro.launch.mesh import make_test_mesh
-from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
-
-g = synth_genome(200_000, seed={seed})
-rs = simulate_reads(g, {n_reads}, ReadSimConfig(read_len={read_len},
-                                                error_rate=0.10,
-                                                seed={seed} + 1))
-cfg = AlignerConfig(W=64, O=24, k=12, backend={backend!r})
-rep = {{}}
-for name, mesh in (('1dev', None),
-                   ('{n_devices}dev', make_test_mesh(({n_devices},),
-                                                     ('data',)))):
-    al = GenASMAligner(cfg, rescue_rounds=1, mesh=mesh)
-    al.align(rs.reads, rs.ref_segments)          # warm / compile
-    transfer.reset()
-    ts = []
-    for _ in range(3):
-        t0 = time.time()
-        al.align(rs.reads, rs.ref_segments)
-        ts.append(time.time() - t0)
-    s = transfer.stats()
-    rep[name] = {{'wall_s': sorted(ts)[1], 'h2d_bytes': s.h2d_bytes // 3,
-                 'd2h_bytes': s.d2h_bytes // 3,
-                 'h2d_calls': s.h2d_calls // 3,
-                 'd2h_calls': s.d2h_calls // 3}}
-print(json.dumps(rep))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, timeout=900)
-    if r.returncode != 0:
-        raise RuntimeError(r.stdout + r.stderr)
-    rep = _json.loads(r.stdout.strip().splitlines()[-1])
+    args = (n_devices, n_reads, read_len, seed, backend)
+    if on_cpu:
+        script = ("import json\n"
+                  "from benchmarks.bench_aligners import sharded_vs_single\n"
+                  f"print(json.dumps(sharded_vs_single(*{args!r})))\n")
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={n_devices}"
+        env.setdefault("PYTHONPATH", "src")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env["PYTHONPATH"] = os.pathsep.join([root, env["PYTHONPATH"]])
+        r = subprocess.run([sys.executable, "-c", script],
+                           capture_output=True, text=True, env=env,
+                           timeout=900)
+        if r.returncode != 0:
+            raise RuntimeError(r.stdout + r.stderr)
+        rep = _json.loads(r.stdout.strip().splitlines()[-1])
+    else:
+        rep = sharded_vs_single(*args)
     rows, derived = [], {"n_devices": n_devices, "n_reads": n_reads}
     for name, d in rep.items():
         ndev = n_devices if name != "1dev" else 1

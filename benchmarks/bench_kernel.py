@@ -22,8 +22,8 @@ from repro.core.genasm import dc_dmajor, dc_jmajor
 from repro.core.traceback import traceback
 from repro.kernels.genasm_dc import (default_max_ops, default_max_steps,
                                      vmem_bytes, vmem_bytes_tail)
-from repro.kernels.ops import (genasm_dc_op, genasm_tail_fused_op,
-                               genasm_tb_fused_op)
+from repro.kernels.ops import (default_interpret, genasm_dc_op,
+                               genasm_tail_fused_op, genasm_tb_fused_op)
 
 
 def _t(fn, reps=3):
@@ -120,9 +120,11 @@ def fused_vs_split(B=256, W=32, k=7, tile=128):
     wl = jnp.full((B,), W, jnp.int32)
     stride = cfg.stride
     max_ops, max_steps = default_max_ops(cfg), default_max_steps(cfg)
+    interpret = default_interpret(cfg.backend)
 
     def split():
-        dist, band, lvl = genasm_dc_op(pat, txt, cfg=cfg, tile=tile)
+        dist, band, lvl = genasm_dc_op(pat, txt, cfg=cfg, tile=tile,
+                                       interpret=interpret)
         tb = traceback({"Rb": band}, pat, txt, wl, wl, dist,
                        jnp.int32(stride), cfg=cfg, mode="band",
                        max_ops=max_ops, max_steps=max_steps)
@@ -131,7 +133,7 @@ def fused_vs_split(B=256, W=32, k=7, tile=128):
     def fused():
         return genasm_tb_fused_op(pat, txt, cfg=cfg, commit_limit=stride,
                                   max_ops=max_ops, max_steps=max_steps,
-                                  tile=tile)["n_ops"]
+                                  tile=tile, interpret=interpret)["n_ops"]
 
     t_split = _t(lambda: jax.block_until_ready(split()))
     t_fused = _t(lambda: jax.block_until_ready(fused()))
@@ -160,6 +162,7 @@ def tail_fused_vs_split(B=128, W=32, k=7, tile=64):
     cfg = AlignerConfig(W=W, O=max(1, W // 3), k=k)
     wt = W + 4 * k
     max_ops_t, max_steps_t = W + wt, W + wt + 4
+    interpret = default_interpret(cfg.backend)
     pat = np.full((B, W), SENTINEL_PAT, np.uint8)
     txt = np.full((B, wt), SENTINEL_TEXT, np.uint8)
     ml = np.zeros(B, np.int32)
@@ -191,7 +194,7 @@ def tail_fused_vs_split(B=128, W=32, k=7, tile=64):
         return genasm_tail_fused_op(patj, txtj, mlj, nlj, cfg=cfg, n_text=wt,
                                     commit_limit=2 * (W + wt),
                                     max_ops=max_ops_t, max_steps=max_steps_t,
-                                    tile=tile)["n_ops"]
+                                    tile=tile, interpret=interpret)["n_ops"]
 
     t_split = _t(lambda: jax.block_until_ready(split()))
     t_fused = _t(lambda: jax.block_until_ready(fused()))

@@ -56,6 +56,8 @@ def main() -> None:
                          "into DIR — uploaded as nightly CI artifacts")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     meta = _meta()
     print(f"# meta: jax={meta['jax_version']} cpus={meta['cpu_count']} "
           f"sha={meta['git_sha']} at={meta['timestamp_utc']}")
@@ -150,13 +152,6 @@ def main() -> None:
         rows, derived = bench_aligners.multidevice(n_devices=args.devices)
         emit(rows)
         all_derived["multidevice"] = derived
-
-    try:
-        from benchmarks import roofline_table
-        rows, _ = roofline_table.rows()
-        emit(rows)
-    except Exception as e:  # dry-run cells not generated yet
-        print(f"roofline/unavailable,0.0,{e}")
 
     print("# derived summary (JSON):")
     print(json.dumps(all_derived, indent=1, default=float))

@@ -9,6 +9,7 @@ and accuracy.  This is the paper-native e2e pipeline (the aligner is the
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.api import plan
@@ -16,6 +17,7 @@ from repro.core.config import AlignerConfig
 from repro.core.oracle import validate_cigar
 from repro.data.genome import (ReadSimConfig, candidate_chains, simulate_reads,
                                synth_genome)
+from repro.launch.compile_cache import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--reads", type=int, default=16)
@@ -28,11 +30,11 @@ ap.add_argument("--backend", choices=("jnp", "pallas", "pallas_fused",
                                       "pallas_gpu"), default="jnp",
                 help="aligner execution path (docs/backends.md)")
 args = ap.parse_args()
+enable_compile_cache()
 
 if args.backend != "jnp":
     # the backend names a lowering; default_interpret decides where it
     # actually runs on this host (docs/backends.md)
-    import jax
     from repro.kernels.ops import default_interpret
     mode = "interpret" if default_interpret(args.backend) else "compiled"
     print(f"backend {args.backend}: {mode} mode on this host "
@@ -80,8 +82,8 @@ print(f"aligned true loci: {aligned_true}/{n_true}; "
       f"rejected decoys: {rejected_decoys}/{len(chains)-n_true}")
 print(f"summary: {res.summary(base_k=session.cfg.k)}")
 print(f"steady-state: {t_steady:.2f}s = {len(chains)/t_steady:.1f} pairs/s = "
-      f"{bp/t_steady/1e6:.2f} Mbp/s (single CPU core, {args.backend} "
-      f"backend)")
+      f"{bp/t_steady/1e6:.2f} Mbp/s ({args.backend} backend on "
+      f"{jax.default_backend()})")
 print(f"mean edit distance of true alignments: "
       f"{np.mean([res.dist[i] for i in range(len(chains)) if i % (1+args.decoys)==0 and ok[i]]):.1f} "
       f"(expected ~{args.error_rate*args.rlen*0.95:.0f})")

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.data.genome import (ReadSimConfig, plant_decoys, simulate_reads,
                                synth_genome)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mapper import MapperConfig, ReadMapper
 
 ap = argparse.ArgumentParser()
@@ -32,6 +33,7 @@ ap.add_argument("--W", type=int, default=64)
 ap.add_argument("--fast", action="store_true",
                 help="CI smoke size: small geometry, fewer/shorter reads")
 args = ap.parse_args()
+enable_compile_cache()
 if args.fast:
     args.reads, args.rlen, args.genome, args.W = 24, 400, 120_000, 32
 

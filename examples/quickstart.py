@@ -9,7 +9,9 @@ from repro.core.aligner import GenASMAligner
 from repro.core.config import AlignerConfig
 from repro.core.counting import reduction_report
 from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
+from repro.launch.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 genome = synth_genome(100_000, seed=1)
 rs = simulate_reads(genome, 4, ReadSimConfig(read_len=600, error_rate=0.08,
                                              seed=2))

@@ -16,6 +16,7 @@ import numpy as np
 from repro.api import plan
 from repro.core.config import AlignerConfig
 from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
+from repro.launch.compile_cache import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--requests", type=int, default=32)
@@ -41,6 +42,7 @@ ap.add_argument("--metrics-dump", action="store_true",
                      "above is derivable from this dump — see "
                      "docs/observability.md)")
 args = ap.parse_args()
+enable_compile_cache()
 
 cfg = AlignerConfig(W=32, O=12, k=8, backend=args.backend) if args.fast \
     else AlignerConfig(W=64, O=24, k=12, backend=args.backend)

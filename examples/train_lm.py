@@ -7,12 +7,14 @@ lowers unchanged onto the production mesh — see launch/dryrun.py).
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="llama3.2-1b")
 ap.add_argument("--steps", type=int, default=200)
 args = ap.parse_args()
+enable_compile_cache()
 
 train_main([
     "--arch", args.arch, "--tiny", "--layers", "4",

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .bitops import SENTINEL_PAT, SENTINEL_TEXT
-from .config import AlignerConfig
+from .config import PALLAS_BACKENDS, AlignerConfig
 from .genasm import dc, dc_jmajor
 from .traceback import OP_NONE, traceback
 
@@ -99,7 +99,11 @@ def plan_lane_tile(cfg: AlignerConfig, vmem_budget_bytes: int = 16 * 2**20,
     bytes get *spent*: the tail kernel's store was the binding constraint,
     and the Scrooge-style band (cfg.tail_banded) roughly halves it at the
     default geometry, so the planner's ceiling doubles — more lanes per
-    kernel launch, fewer grid steps per batch.
+    kernel launch, fewer grid steps per batch.  The budget is half the
+    scoped-VMEM limit the kernels ask Mosaic for
+    (kernels.genasm_dc.VMEM_LIMIT_BYTES); the other half holds pipeline
+    buffers and walk temporaries.  tests/test_tpu_compile.py compiles the
+    planned tile for a described v5e.
 
     backend='pallas_gpu': a *register* model instead — the Triton lowering
     keeps the band store in GMEM (no scratch memory) and the live DP
@@ -283,13 +287,23 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
 def rescue_schedule(cfg: AlignerConfig, rescue_rounds: int):
     """The k-doubling ladder: round r runs with k_r = min(k * 2**r, W - 1),
     deduplicated once the cap is hit.  Single source of truth for the
-    host-loop and on-device rescue paths (and for padding geometry)."""
+    host-loop and on-device rescue paths (and for padding geometry).
+
+    A Pallas rung keeps the previous rung's lane tile unless its doubled-k
+    stores no longer fit the planner's VMEM budget there; it then drops to
+    its own planned tile (`plan_lane_tile`), so a tile planned for the base
+    k never launches a rung kernel that the TPU compiler refuses.  Per-lane
+    results do not depend on the tile."""
     cfgs = [cfg]
     for _ in range(rescue_rounds):
         new_k = min(cfgs[-1].k * 2, cfg.W - 1)
         if new_k == cfgs[-1].k:
             break
-        cfgs.append(dataclasses.replace(cfgs[-1], k=new_k))
+        rung = dataclasses.replace(cfgs[-1], k=new_k)
+        if rung.backend in PALLAS_BACKENDS:
+            rung = dataclasses.replace(
+                rung, lane_tile=min(rung.lane_tile, plan_lane_tile(rung)))
+        cfgs.append(rung)
     return tuple(cfgs)
 
 

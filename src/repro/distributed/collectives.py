@@ -15,13 +15,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis_name):
-    # jax.lax.axis_size is newer-jax; psum(1) constant-folds to the same.
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _quant(x):
     scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0 + 1e-12
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
@@ -36,7 +29,7 @@ def ring_reduce_scatter_q8(x, axis_name: str):
     """x: (n_shards * chunk,) fp32 per device -> (chunk,) = fully-reduced
     chunk `me`.  The partial sum for chunk c starts at device (c+1)%n and
     rings to c, each hop quantized to int8 + one fp32 scale."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     xs = x.reshape(n, -1)
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -56,7 +49,7 @@ def ring_reduce_scatter_q8(x, axis_name: str):
 def compressed_allreduce(x, axis_name: str):
     """reduce-scatter (int8 ring) + int8 all-gather: psum replacement at
     ~1/4 the wire bytes."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.size) % n
     flat = jnp.pad(flat, (0, pad))
@@ -77,7 +70,7 @@ def make_compressed_grad_sync(mesh, axis_name="data"):
         def inner(g):
             return jax.tree_util.tree_map(
                 lambda a: compressed_allreduce(a, axis_name) /
-                _axis_size(axis_name), g)
+                jax.lax.axis_size(axis_name), g)
         from ..launch.mesh import shard_map
         return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
                          check=False)(grads)

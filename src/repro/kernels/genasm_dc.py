@@ -47,7 +47,7 @@ axis vectorises across the program's threads, ``gpu_num_warps`` warps of
 32), with two mapping differences from the TPU path, both decided here at
 trace time:
 
-  * **No scratch memory.**  jax 0.4.37's Triton lowering rejects
+  * **No scratch memory.**  Pallas's Triton lowering rejects
     ``scratch_shapes`` outright, so the DENT band / SENE store that the
     TPU path keeps in VMEM scratch rides a GMEM-backed *output block*
     instead.  Kernel bodies are reused unchanged — Pallas passes output
@@ -110,14 +110,30 @@ def gpu_num_warps(tile: int) -> int:
     return max(1, min(8, tile // 32))
 
 
-def _gpu_compiler_params(tile: int):
-    """TritonCompilerParams for a compiled GPU launch (unused in interpret
-    mode).  num_stages stays 1: the DC fill is a serial column recurrence —
-    software-pipelining its loads buys nothing and costs registers, the
-    binding resource of the lane-per-thread mapping."""
-    from jax.experimental.pallas import triton as plgpu
-    return plgpu.TritonCompilerParams(num_warps=gpu_num_warps(tile),
-                                      num_stages=1)
+#: Scoped-VMEM limit handed to Mosaic for every TPU kernel: twice the
+#: lane-tile planner's 16 MiB scratch budget (core.windowing.plan_lane_tile).
+#: The other half holds the double-buffered in/out blocks and the traceback
+#: walk's one-hot temporaries, which grow with the tile like the scratch
+#: does; Mosaic's default 16 MiB scope refuses the planned tile.
+#: tests/test_tpu_compile.py compiles every kernel at the planned tile
+#: against a described v5e (128 MiB of VMEM per core) under this limit.
+VMEM_LIMIT_BYTES = 32 * 2**20
+
+
+def _compiler_params(cfg: AlignerConfig, tile: int, interpret: bool):
+    """Lowering parameters for a compiled launch (None in interpret mode).
+
+    TPU: the scoped-VMEM limit above.  Triton: num_stages stays 1 — the DC
+    fill is a serial column recurrence, so software-pipelining its loads
+    buys nothing and costs registers, the binding resource of the
+    lane-per-thread mapping."""
+    if interpret:
+        return None
+    if cfg.backend == "pallas_gpu":
+        from jax.experimental.pallas import triton as plgpu
+        return plgpu.CompilerParams(num_warps=gpu_num_warps(tile),
+                                    num_stages=1)
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def fused_scratch_shapes(cfg: AlignerConfig, tile: int):
@@ -194,6 +210,54 @@ def _pm_lookup(pm_ref, cj, nw, n_sym=4):
     return out
 
 
+def _text_row(text_ref, row):
+    """Text chars of `row` (a scalar, or one row per lane), in range: a
+    one-hot sum over the static row axis of the whole (rows, TB) block.
+
+    Every ref access in these kernels has a static address, and every
+    loop starts at 0.  On a v5e a kernel whose loop ran from 1 and loaded
+    row `j - 1` of its loop index never returned, while the same load in
+    a loop from 0 did; the kernels as run on the chip avoid both (see
+    `_set_row`)."""
+    t = text_ref[:, :]
+    rows = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
+    return jnp.sum(jnp.where(rows == row, t, 0), axis=0).astype(jnp.int32)
+
+
+def _set_row(ref, d, w, row, value):
+    """ref[d, row, w, :] = value for a traced scalar `row`, written as a
+    select over the whole static (rows, TB) slab ref[d, :, w, :], so the
+    store's address is static (see `_text_row`).  A row out of range
+    leaves the slab unchanged."""
+    slab = ref[d, :, w, :]
+    rows = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0)
+    ref[d, :, w, :] = jnp.where(rows == row, value[None, :], slab)
+
+
+def _bool_select(c, a, b):
+    """jnp.where over boolean vectors, as logic: Mosaic cannot select
+    between i1 vectors."""
+    return (c & a) | (~c & b)
+
+
+def _gather_cell(ref, w, dd, col):
+    """Per-lane read of ref[dd, col, w, :] (uint32) for per-lane level dd
+    and column col, both already clipped into range.
+
+    Two stages keep every temporary at one (columns, TB) slab instead of
+    the whole (levels, columns, TB) store: the level slab is selected per
+    lane across the small static level axis, then the column is picked by
+    a one-hot sum.  The sum runs in int32 because Mosaic has no unsigned
+    reductions; it is exact, since at most one addend is non-zero."""
+    slab = ref[0, :, w, :]
+    for d in range(1, ref.shape[0]):
+        slab = jnp.where((dd == d)[None, :], ref[d, :, w, :], slab)
+    c_ids = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0)
+    v = jax.lax.bitcast_convert_type(slab, jnp.int32)
+    s = jnp.sum(jnp.where(c_ids == col[None, :], v, 0), axis=0)
+    return jax.lax.bitcast_convert_type(s, jnp.uint32)
+
+
 def _shift1_words(words, carry_in, nw):
     """Left-shift a word-list bitvector (LSW first) by one; carry_in at bit 0.
     words: list of nw (TB,) uint32."""
@@ -254,11 +318,16 @@ def _ids_dist_dend(last_cols, bit_w, bit_o, guard, cfg):
         hit = (bit == 0) & guard
         full = jnp.full(hit.shape, k + 1, jnp.int32)
         dist = jnp.where(hit, d, full if dist is None else dist)
+    return dist, _levels_run(dist, cfg)
+
+
+def _levels_run(dist, cfg):
+    """The whole-tile-ET level count as a (1,) lane vector: levels
+    1..max(dist) (capped at k) ran, exit at the next; k+1 without ET.
+    Kept in vector registers (keepdims) — no vector-to-scalar move."""
     if cfg.early_term:
-        d_end = jnp.minimum(jnp.max(dist), k) + 1
-    else:
-        d_end = jnp.int32(k + 1)
-    return dist, d_end
+        return jnp.minimum(jnp.max(dist, keepdims=True), cfg.k) + 1
+    return jnp.full((1,), cfg.k + 1, jnp.int32)
 
 
 def _dc_phase(pm_ref, text_ref, band_ref, *, cfg: AlignerConfig):
@@ -293,7 +362,7 @@ def _dc_phase(pm_ref, text_ref, band_ref, *, cfg: AlignerConfig):
             win = jnp.where(s == 0, lo, (lo >> s) | (hi << (jnp.uint32(WORD) - s)))
             @pl.when(j >= col0)
             def _():
-                band_ref[d, j - col0, b, :] = win
+                _set_row(band_ref, d, b, j - col0, win)
 
     lane_shape = text_ref.shape[1:]
     cols0 = [_ones_below_words(jnp.int32(d), nw, lane_shape)
@@ -302,11 +371,10 @@ def _dc_phase(pm_ref, text_ref, band_ref, *, cfg: AlignerConfig):
         for d in range(k + 1):
             store_band(d, jnp.int32(0), cols0[d])
 
-    def col_body(j, carry):
+    def col_body(t, carry):               # t = j - 1: text index of column j
+        j = t + 1
         prev = [list(c) for c in carry]
-        cj = text_ref[j - 1, :].astype(jnp.int32)
-        pm_j = _pm_lookup(pm_ref, cj, nw)
-        t = j - 1
+        pm_j = _pm_lookup(pm_ref, _text_row(text_ref, t), nw)
         cur = [_next_column([prev[0]], None, pm_j, t, 0, nw)]
         for d in range(1, k + 1):
             cur.append(_next_column([prev[d], prev[d - 1]], cur[d - 1],
@@ -315,8 +383,7 @@ def _dc_phase(pm_ref, text_ref, band_ref, *, cfg: AlignerConfig):
             store_band(d, j, cur[d])
         return tuple(tuple(c) for c in cur)
 
-    last = jax.lax.fori_loop(1, W + 1, col_body,
-                             tuple(tuple(c) for c in cols0))
+    last = jax.lax.fori_loop(0, W, col_body, tuple(tuple(c) for c in cols0))
     guard = jnp.ones(lane_shape, bool)
     return _ids_dist_dend(last, tgt_w, tgt_o, guard, cfg)
 
@@ -336,11 +403,13 @@ def _tb_walk(*, TB, dist, k, init_i, init_j, commit_limit, max_ops, max_steps,
     commit-limit stop.  ``avail_words(dd, jj)`` gathers the stored bitvector
     words of (level dd, column jj); ``zbit(words, dd, jj, ii)`` tests bit ii.
 
-    Returns the final (i, j, d, nops, ops, rd, rf, done, ok) state."""
+    Returns the final (i, j, d, nops, ops, rd, rf, done, ok) state, with
+    done and ok as int32 0/1 flags."""
     slot_ids = jax.lax.broadcasted_iota(jnp.int32, (max_ops, TB), 0)
 
     def body(state):
         i, j, d, nops, ops, rd, rf, done, ok = state
+        done = done != 0
         tail = i < 0
         stopped = rd >= commit_limit
         active = ~done & ~stopped
@@ -385,15 +454,17 @@ def _tb_walk(*, TB, dist, k, init_i, init_j, commit_limit, max_ops, max_steps,
         nops = nops + emit
 
         finished = (new_i < 0) & (new_j <= 0)
-        new_done = done | (active & finished)
+        new_done = done | (active & finished) | stopped
         # invariant: an active, unfinished cell always has an available edge
-        ok &= jnp.where(active & ~finished, any_edge | ((i < 0) & (j <= 0)), True)
+        ok &= (~(active & ~finished) | any_edge
+               | ((i < 0) & (j <= 0))).astype(jnp.int32)
         return (new_i, new_j, new_d, nops, ops, new_rd, new_rf,
-                new_done | stopped, ok)
+                new_done.astype(jnp.int32), ok)
 
-    def walk_body(step, state):
-        del step
-        return jax.lax.cond(jnp.any(~state[7]), body, lambda s: s, state)
+    # done/ok ride the loop carry as int32 0/1: Mosaic cannot carry boolean
+    # vectors through a loop (i8 -> i1 truncation).  Every step runs the
+    # body (finished lanes are masked by `active`): skipping a step for the
+    # whole tile would need a vector-to-scalar move per step.
 
     zeros = jnp.zeros((TB,), jnp.int32)
     skip = dist > k
@@ -405,10 +476,10 @@ def _tb_walk(*, TB, dist, k, init_i, init_j, commit_limit, max_ops, max_steps,
         jnp.full((max_ops, TB), OP_NONE, jnp.int32),
         zeros,                                      # read_adv
         zeros,                                      # ref_adv
-        skip,                                       # done
-        jnp.ones((TB,), bool),                      # ok
+        skip.astype(jnp.int32),                     # done
+        jnp.ones((TB,), jnp.int32),                 # ok
     )
-    return jax.lax.fori_loop(0, max_steps, walk_body, init)
+    return jax.lax.fori_loop(0, max_steps, lambda _, s: body(s), init)
 
 
 def _kernel_fused(pm_ref, text_ref, ops_ref, meta_ref, band_ref, *,
@@ -435,17 +506,12 @@ def _kernel_fused(pm_ref, text_ref, ops_ref, meta_ref, band_ref, *,
     dist, d_end = _dc_phase(pm_ref, text_ref, band_ref, cfg=cfg)
 
     # ---------------- traceback phase ----------------
-    d_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, ncb, TB), 0)
-    s_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, ncb, TB), 1)
-    t_ids = jax.lax.broadcasted_iota(jnp.int32, (W, TB), 0)
-
     def band_words(dd, jj):
         """Per-lane gather of the stored band window of (level dd, col jj),
         clipped like core.traceback._zbit_band."""
-        onehot = ((d_ids == jnp.clip(dd, 0, k)[None, None, :]) &
-                  (s_ids == jnp.clip(jj - col0, 0, ncb - 1)[None, None, :]))
-        return [jnp.sum(jnp.where(onehot, band_ref[:, :, b, :], jnp.uint32(0)),
-                        axis=(0, 1), dtype=jnp.uint32) for b in range(nwb)]
+        ddc = jnp.clip(dd, 0, k)
+        col = jnp.clip(jj - col0, 0, ncb - 1)
+        return [_gather_cell(band_ref, b, ddc, col) for b in range(nwb)]
 
     def zbit(words, dd, jj, ii):
         """bit ii of the band window == 0; ii == -1 encodes the DP's first
@@ -456,13 +522,11 @@ def _kernel_fused(pm_ref, text_ref, ops_ref, meta_ref, band_ref, *,
         offc = jnp.clip(off, 0, nwb * WORD - 1)
         o = (offc % WORD).astype(jnp.uint32)
         bit = (_word_select(words, offc // WORD) >> o) & u1
-        return jnp.where(ii < 0, jj <= dd, (bit == 0) & inband)
+        return _bool_select(ii < 0, jj <= dd, (bit == 0) & inband)
 
     def text_at(jj):
         """text char of column jj (= text index jj-1, clipped)."""
-        onehot = t_ids == jnp.clip(jj - 1, 0, W - 1)[None, :]
-        return jnp.sum(jnp.where(onehot, text_ref[:, :], 0),
-                       axis=0).astype(jnp.int32)
+        return _text_row(text_ref, jnp.clip(jj - 1, 0, W - 1))
 
     def peq_at(cj, ii):
         """P[ii] == text char cj, via the PM masks (sentinels never match)."""
@@ -485,12 +549,12 @@ def _kernel_fused(pm_ref, text_ref, ops_ref, meta_ref, band_ref, *,
     meta_ref[META_RD, :] = rd
     meta_ref[META_RF, :] = rf
     meta_ref[META_DFIN, :] = d
-    meta_ref[META_OK, :] = ok.astype(jnp.int32)
+    meta_ref[META_OK, :] = ok
     meta_ref[META_ROWS - 1, :] = jnp.zeros((TB,), jnp.int32)
 
 
 def genasm_dc_pallas(pm, text, *, cfg: AlignerConfig, tile: int = 128,
-                     interpret: bool = True):
+                     interpret: bool):
     """pm: (5, NW, B) uint32; text: (W, B) int32 (kernel layout, problems
     innermost).  Returns (dist (B,), band (k+1, ncb, nwb, B), levels (B,)).
     No VMEM scratch at all: the DC state is loop-carried, the band is the
@@ -501,7 +565,6 @@ def genasm_dc_pallas(pm, text, *, cfg: AlignerConfig, tile: int = 128,
     assert W == cfg.W and nw == cfg.nw and B % tile == 0
     ncb, nwb, k = cfg.ncols_band, cfg.nwb, cfg.k
     grid = (B // tile,)
-    gpu = cfg.backend == "pallas_gpu"
     kern = functools.partial(_kernel, cfg=cfg)
     out = pl.pallas_call(
         kern,
@@ -520,8 +583,7 @@ def genasm_dc_pallas(pm, text, *, cfg: AlignerConfig, tile: int = 128,
             jax.ShapeDtypeStruct((1, B), jnp.int32),
             jax.ShapeDtypeStruct((1, B), jnp.int32),
         ],
-        compiler_params=_gpu_compiler_params(tile)
-        if gpu and not interpret else None,
+        compiler_params=_compiler_params(cfg, tile, interpret),
         interpret=interpret,
     )(pm, text)
     band, dist, lvl = out
@@ -531,7 +593,7 @@ def genasm_dc_pallas(pm, text, *, cfg: AlignerConfig, tile: int = 128,
 def genasm_tb_fused_pallas(pm, text, *, cfg: AlignerConfig, commit_limit: int,
                            max_ops: int | None = None,
                            max_steps: int | None = None, tile: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """Fused DC+TB.  pm: (5, NW, B) uint32; text: (W, B) int32 (kernel
     layout).  Returns (ops (max_ops, B) int32 front-first with OP_NONE
     padding, meta (META_ROWS, B) int32 — see META_* row constants).  The
@@ -577,12 +639,51 @@ def genasm_tb_fused_pallas(pm, text, *, cfg: AlignerConfig, commit_limit: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=() if gpu else fused_scratch_shapes(cfg, tile),
-        compiler_params=_gpu_compiler_params(tile)
-        if gpu and not interpret else None,
+        compiler_params=_compiler_params(cfg, tile, interpret),
         interpret=interpret,
     )(pm, text)
     ops, meta = out[0], out[1]       # gpu: out[2] is the discarded band
     return ops, meta
+
+
+def _tail_fill(pm_ref, text_ref, m_len, n_len, store_col, *,
+               cfg: AlignerConfig, n_text: int):
+    """Column-major DC fill of the ragged rectangular tail, shared by both
+    tail kernels: all k+1 levels of the live column ride in the loop carry
+    (no full-table reads), and ``store_col(d, j, words)`` stores column
+    j >= 1 of level d.  Keeps dc_jmajor's ragged semantics: columns past a
+    lane's n_len freeze their left neighbour, and dist reads the per-lane
+    bit (m_len - 1) of the final carried column (== frozen column n_len);
+    empty lanes (m_len == 0) never hit.  Every level is filled and d_end
+    reproduces the whole-tile-ET level count analytically
+    (`_ids_dist_dend`); the walk never visits a level above its lane's
+    dist, so the extra levels cannot change results.  Returns
+    (dist, d_end)."""
+    k, nw, m_pad = cfg.k, cfg.nw, cfg.m_pad
+    TB = text_ref.shape[1]
+    cols0 = [_ones_below_words(jnp.int32(d), nw, (TB,)) for d in range(k + 1)]
+
+    def col_body(t, carry):               # t = j - 1: text index of column j
+        j = t + 1
+        prev = [list(c) for c in carry]
+        pm_j = _pm_lookup(pm_ref, _text_row(text_ref, t), nw)
+        live = j <= n_len
+        cur = []
+        for d in range(k + 1):
+            below = cur[d - 1] if d else None
+            r = _next_column([prev[d]] if d == 0 else [prev[d], prev[d - 1]],
+                             below, pm_j, t, d, nw)
+            cur.append([jnp.where(live, rw, pw)
+                        for rw, pw in zip(r, prev[d])])
+        for d in range(k + 1):
+            store_col(d, j, cur[d])
+        return tuple(tuple(c) for c in cur)
+
+    last = jax.lax.fori_loop(0, n_text, col_body,
+                             tuple(tuple(c) for c in cols0))
+    tm = jnp.clip(m_len - 1, 0, m_pad - 1)
+    return _ids_dist_dend(last, tm // WORD, (tm % WORD).astype(jnp.uint32),
+                          m_len >= 1, cfg)
 
 
 def _kernel_tail_fused(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref, meta_ref,
@@ -599,115 +700,42 @@ def _kernel_tail_fused(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref, meta_ref,
     dispatched only when the banded store (`_kernel_tail_banded`) is not a
     strict win (cfg.tail_banded False, i.e. nwb == nw or forced 'full').
 
-    Mirrors dc_jmajor semantics: columns beyond a lane's n_len are frozen
-    copies of their left neighbour (hence of column n_len), dist reads the
-    per-lane bit (m_len - 1) of the final column, and the level loop runs
-    whole-tile early termination — the traceback never visits a level above
-    its lane's dist, so ET cannot change results vs the ET-free jnp fill.
+    The fill is `_tail_fill`, shared with the banded kernel; this variant
+    stores every column whole, column 0 (R_0[d] = ones_below(d)) included.
     """
-    W, k, nw = cfg.W, cfg.k, cfg.nw
+    k, nw = cfg.k, cfg.nw
     m_pad = cfg.m_pad
     TB = text_ref.shape[1]
     u1 = jnp.uint32(1)
     m_len = mlen_ref[0, :]
     n_len = nlen_ref[0, :]
 
-    # deterministic reads for ET-skipped levels (never walked, see above)
-    rfull_ref[:, :, :, :] = jnp.zeros((k + 1, n_text + 1, nw, TB), jnp.uint32)
+    for d in range(k + 1):
+        for w, word in enumerate(_ones_below_words(jnp.int32(d), nw, (TB,))):
+            rfull_ref[d, 0, w, :] = word
 
-    def col_get(d, j):
-        return [rfull_ref[d, j, w, :] for w in range(nw)]
-
-    def col_set(d, j, words):
+    def store_col(d, j, words):
         for w in range(nw):
-            rfull_ref[d, j, w, :] = words[w]
+            _set_row(rfull_ref, d, w, j, words[w])
 
-    def level_hit(d):
-        """Per-lane bit (m_len - 1) of the final column == 0.  Empty lanes
-        (m_len == 0) never hit, matching the jnp path's sentinel-region
-        read of bit -1 for every k < WORD - 1 geometry."""
-        last = col_get(d, n_text)
-        t = jnp.clip(m_len - 1, 0, m_pad - 1)
-        o = (t % WORD).astype(jnp.uint32)
-        bit = (_word_select(last, t // WORD) >> o) & u1
-        return (bit == 0) & (m_len >= 1)
-
-    # ---------------- level 0 ----------------
-    col_set(0, 0, _ones_below_words(jnp.int32(0), nw, (TB,)))
-
-    def col_body0(j, _):
-        prev = col_get(0, j - 1)
-        pm_j = _pm_lookup(pm_ref, text_ref[j - 1, :].astype(jnp.int32), nw)
-        bM = ((j - 1) > 0).astype(jnp.uint32)
-        r = [a | b for a, b in zip(_shift1_words(prev, bM, nw), pm_j)]
-        live = j <= n_len
-        col_set(0, j, [jnp.where(live, rw, pw) for rw, pw in zip(r, prev)])
-        return 0
-
-    jax.lax.fori_loop(1, n_text + 1, col_body0, 0)
-    dist0 = jnp.where(level_hit(0), 0, k + 1).astype(jnp.int32)
-
-    # ---------------- levels 1..k with early termination ----------------
-    def fill_level(d):
-        col_set(d, 0, _ones_below_words(d, nw, (TB,)))
-
-        def col_body(j, _):
-            r_prev = col_get(d, j - 1)        # R_{j-1}[d]
-            p_jm1 = col_get(d - 1, j - 1)     # R_{j-1}[d-1]
-            p_j = col_get(d - 1, j)           # R_j[d-1]
-            pm_j = _pm_lookup(pm_ref, text_ref[j - 1, :].astype(jnp.int32), nw)
-            t = j - 1
-            bM = (t > d).astype(jnp.uint32)
-            bS = (t >= d).astype(jnp.uint32)
-            bI = (t >= d - 1).astype(jnp.uint32)
-            M = [a | b for a, b in zip(_shift1_words(r_prev, bM, nw), pm_j)]
-            S = _shift1_words(p_jm1, bS, nw)
-            I = _shift1_words(p_j, bI, nw)
-            r = [M[w] & S[w] & p_jm1[w] & I[w] for w in range(nw)]
-            live = j <= n_len
-            col_set(d, j, [jnp.where(live, rw, pw)
-                           for rw, pw in zip(r, r_prev)])
-            return 0
-
-        jax.lax.fori_loop(1, n_text + 1, col_body, 0)
-        return level_hit(d)
-
-    def lvl_cond(state):
-        d, dist = state
-        go = d <= k
-        if cfg.early_term:
-            go &= jnp.any(dist > k)
-        return go
-
-    def lvl_body(state):
-        d, dist = state
-        hit = fill_level(d)
-        return d + 1, jnp.where((dist > k) & hit, d, dist).astype(jnp.int32)
-
-    d_end, dist = jax.lax.while_loop(lvl_cond, lvl_body, (jnp.int32(1), dist0))
+    dist, d_end = _tail_fill(pm_ref, text_ref, m_len, n_len, store_col,
+                             cfg=cfg, n_text=n_text)
 
     # ------- traceback phase: full-vector zbit, like core.traceback 'and' ---
-    d_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, n_text + 1, TB), 0)
-    c_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, n_text + 1, TB), 1)
-    t_ids = jax.lax.broadcasted_iota(jnp.int32, (n_text, TB), 0)
-
     def r_words(dd, jj):
         """Per-lane gather of stored R_jj[dd], clipped like _zbit_full."""
-        onehot = ((d_ids == jnp.clip(dd, 0, k)[None, None, :]) &
-                  (c_ids == jnp.clip(jj, 0, n_text)[None, None, :]))
-        return [jnp.sum(jnp.where(onehot, rfull_ref[:, :, w, :], jnp.uint32(0)),
-                        axis=(0, 1), dtype=jnp.uint32) for w in range(nw)]
+        ddc = jnp.clip(dd, 0, k)
+        col = jnp.clip(jj, 0, n_text)
+        return [_gather_cell(rfull_ref, w, ddc, col) for w in range(nw)]
 
     def zbit(words, dd, jj, ii):
         iic = jnp.clip(ii, 0, m_pad - 1)
         o = (iic % WORD).astype(jnp.uint32)
         bit = (_word_select(words, iic // WORD) >> o) & u1
-        return jnp.where(ii < 0, jj <= dd, bit == 0)
+        return _bool_select(ii < 0, jj <= dd, bit == 0)
 
     def text_at(jj):
-        onehot = t_ids == jnp.clip(jj - 1, 0, n_text - 1)[None, :]
-        return jnp.sum(jnp.where(onehot, text_ref[:, :], 0),
-                       axis=0).astype(jnp.int32)
+        return _text_row(text_ref, jnp.clip(jj - 1, 0, n_text - 1))
 
     def peq_at(cj, ii):
         words = _pm_lookup(pm_ref, cj, nw)
@@ -727,7 +755,7 @@ def _kernel_tail_fused(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref, meta_ref,
     meta_ref[META_RD, :] = rd
     meta_ref[META_RF, :] = rf
     meta_ref[META_DFIN, :] = d
-    meta_ref[META_OK, :] = ok.astype(jnp.int32)
+    meta_ref[META_OK, :] = ok
     meta_ref[META_ROWS - 1, :] = jnp.zeros((TB,), jnp.int32)
 
 
@@ -749,15 +777,9 @@ def _kernel_tail_banded(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref,
     lane sits on its own diagonal.  Column 0 (R_0[d] = ones_below(d)) and
     the i < 0 drain are analytic in zbit, so they need no store at all.
 
-    The fill is column-major (two live columns in the loop carry, all k+1
-    levels unrolled — no full-table scratch), with dc_jmajor's ragged
-    semantics preserved: columns beyond a lane's n_len freeze their left
-    neighbour, and dist reads the per-lane bit (m_len - 1) of the final
-    carried column.  d_end reproduces the whole-tile-ET level count
-    analytically (see _ids_dist_dend); the walk never visits a level above
-    its lane's dist, so the extra computed levels cannot change results.
+    The fill is `_tail_fill`, shared with the full-store kernel.
     """
-    W, k, nw, nwb = cfg.W, cfg.k, cfg.nw, cfg.nwb
+    k, nw, nwb = cfg.k, cfg.nw, cfg.nwb
     m_pad = cfg.m_pad
     TB = text_ref.shape[1]
     u1 = jnp.uint32(1)
@@ -783,49 +805,18 @@ def _kernel_tail_banded(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref,
                                jnp.where(w0 + b + 1 >= nw, jnp.uint32(0xFFFFFFFF),
                                          hi))
             win = jnp.where(s == 0, lo, (lo >> s) | (hi << (jnp.uint32(WORD) - s)))
-            band_ref[d, j - 1, b, :] = win
+            _set_row(band_ref, d, b, j - 1, win)
 
-    # ------- column-major fill: live columns in the carry, band stored -----
-    cols0 = [_ones_below_words(jnp.int32(d), nw, (TB,)) for d in range(k + 1)]
-
-    def col_body(j, carry):
-        prev = [list(c) for c in carry]
-        pm_j = _pm_lookup(pm_ref, text_ref[j - 1, :].astype(jnp.int32), nw)
-        live = j <= n_len
-        t = j - 1
-        cur = []
-        for d in range(k + 1):
-            below = cur[d - 1] if d else None
-            r = _next_column([prev[d]] if d == 0 else [prev[d], prev[d - 1]],
-                             below, pm_j, t, d, nw)
-            cur.append([jnp.where(live, rw, pw)
-                        for rw, pw in zip(r, prev[d])])
-        for d in range(k + 1):
-            store_band(d, j, cur[d])
-        return tuple(tuple(c) for c in cur)
-
-    last = jax.lax.fori_loop(1, n_text + 1, col_body,
-                             tuple(tuple(c) for c in cols0))
-
-    # dist from the final carried column (== frozen column n_len), exactly
-    # level_hit of the full-store variant; empty lanes (m_len == 0) never hit
-    tm = jnp.clip(m_len - 1, 0, m_pad - 1)
-    dist, d_end = _ids_dist_dend(last, tm // WORD,
-                                 (tm % WORD).astype(jnp.uint32),
-                                 m_len >= 1, cfg)
+    dist, d_end = _tail_fill(pm_ref, text_ref, m_len, n_len, store_band,
+                             cfg=cfg, n_text=n_text)
 
     # ---------------- traceback phase: banded zbit ----------------
-    d_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, n_text, TB), 0)
-    c_ids = jax.lax.broadcasted_iota(jnp.int32, (k + 1, n_text, TB), 1)
-    t_ids = jax.lax.broadcasted_iota(jnp.int32, (n_text, TB), 0)
-
     def band_words(dd, jj):
         """Per-lane gather of the window of (level dd, col jj); column 0 has
         no store (analytic in zbit), so jj clips into 1..n_text."""
-        onehot = ((d_ids == jnp.clip(dd, 0, k)[None, None, :]) &
-                  (c_ids == (jnp.clip(jj, 1, n_text) - 1)[None, None, :]))
-        return [jnp.sum(jnp.where(onehot, band_ref[:, :, b, :], jnp.uint32(0)),
-                        axis=(0, 1), dtype=jnp.uint32) for b in range(nwb)]
+        ddc = jnp.clip(dd, 0, k)
+        col = jnp.clip(jj, 1, n_text) - 1
+        return [_gather_cell(band_ref, b, ddc, col) for b in range(nwb)]
 
     def zbit(words, dd, jj, ii):
         """bit ii of R_jj[dd] == 0 from the banded store; analytic for the
@@ -837,13 +828,11 @@ def _kernel_tail_banded(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref,
         offc = jnp.clip(off, 0, nwb * WORD - 1)
         o = (offc % WORD).astype(jnp.uint32)
         bit = (_word_select(words, offc // WORD) >> o) & u1
-        z = jnp.where(jj <= 0, ii < dd, (bit == 0) & inband)
-        return jnp.where(ii < 0, jj <= dd, z)
+        z = _bool_select(jj <= 0, ii < dd, (bit == 0) & inband)
+        return _bool_select(ii < 0, jj <= dd, z)
 
     def text_at(jj):
-        onehot = t_ids == jnp.clip(jj - 1, 0, n_text - 1)[None, :]
-        return jnp.sum(jnp.where(onehot, text_ref[:, :], 0),
-                       axis=0).astype(jnp.int32)
+        return _text_row(text_ref, jnp.clip(jj - 1, 0, n_text - 1))
 
     def peq_at(cj, ii):
         words = _pm_lookup(pm_ref, cj, nw)
@@ -863,14 +852,14 @@ def _kernel_tail_banded(pm_ref, text_ref, mlen_ref, nlen_ref, ops_ref,
     meta_ref[META_RD, :] = rd
     meta_ref[META_RF, :] = rf
     meta_ref[META_DFIN, :] = d
-    meta_ref[META_OK, :] = ok.astype(jnp.int32)
+    meta_ref[META_OK, :] = ok
     meta_ref[META_ROWS - 1, :] = jnp.zeros((TB,), jnp.int32)
 
 
 def genasm_tail_fused_pallas(pm, text, m_len, n_len, *, cfg: AlignerConfig,
                              n_text: int, commit_limit: int, max_ops: int,
                              max_steps: int, tile: int = 128,
-                             interpret: bool = True):
+                             interpret: bool):
     """Fused rectangular-tail DC+TB.  pm: (5, NW, B) uint32; text:
     (n_text, B) int32; m_len/n_len: (1, B) int32 (kernel layout, problems
     innermost).  Returns (ops (max_ops, B) int32, meta (META_ROWS, B) int32)
@@ -917,8 +906,7 @@ def genasm_tail_fused_pallas(pm, text, m_len, n_len, *, cfg: AlignerConfig,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=() if gpu else tail_scratch_shapes(cfg, tile, n_text),
-        compiler_params=_gpu_compiler_params(tile)
-        if gpu and not interpret else None,
+        compiler_params=_compiler_params(cfg, tile, interpret),
         interpret=interpret,
     )(pm, text, m_len, n_len)
     ops, meta = out[0], out[1]       # gpu: out[2] is the discarded store

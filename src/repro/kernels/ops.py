@@ -1,11 +1,13 @@
 """jit'd wrappers for the Pallas kernels (layout marshalling + dispatch).
 
-On this CPU container the kernels execute in interpret mode; on a real
-accelerator pass interpret=False — cfg.backend picks the lowering the
-kernel wrappers build ('pallas'/'pallas_fused' → Mosaic TPU with VMEM
-scratch, 'pallas_gpu' → Triton with the store as a GMEM output block; see
-kernels.genasm_dc and docs/backends.md).  ``default_interpret(cfg.backend)``
-is the one place that decides interpret-vs-compiled from the platform.
+Every op takes ``interpret`` from its caller; there is no default.
+cfg.backend picks the lowering the kernel wrappers build
+('pallas'/'pallas_fused' → Mosaic TPU with VMEM scratch, 'pallas_gpu' →
+Triton with the store as a GMEM output block; see kernels.genasm_dc and
+docs/backends.md).  ``default_interpret(cfg.backend)`` is the one place
+that decides it from the platform: the Pallas interpreter on the CPU, the
+compiled lowering on the platform it targets, and a ValueError anywhere
+else — a TPU never runs a kernel interpreted.
 
 Multi-device: every op takes an optional ``mesh``.  When given, the
 pallas_call is wrapped in ``shard_map`` over the mesh's pair axes
@@ -45,15 +47,25 @@ from .genasm_dc import (META_DFIN, META_DIST, META_LVL, META_NOPS, META_OK,
 GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 
 
-def default_interpret(backend: str | None = None) -> bool:
-    """Interpret-mode Pallas everywhere the cfg.backend's real lowering
-    target is absent: 'pallas_gpu' compiles only on a CUDA/ROCm device,
-    the TPU backends only on a real TPU — CPU CI interprets both.  Called
-    with cfg.backend by every dispatch site (core.windowing, core.genasm);
-    the no-argument form keeps the historical TPU-only contract."""
-    if backend == "pallas_gpu":
-        return jax.default_backend() not in GPU_PLATFORMS
-    return jax.default_backend() != "tpu"
+def default_interpret(backend: str) -> bool:
+    """Whether cfg.backend's kernels run in the Pallas interpreter here.
+
+    True on the CPU only.  False on the platform the backend's lowering
+    compiles for: a TPU for 'pallas'/'pallas_fused', a CUDA/ROCm device for
+    'pallas_gpu'.  Any other pairing raises ValueError (e.g. 'pallas_gpu'
+    on a TPU): interpreting there would hide the device behind a CPU-speed
+    fallback.  Called with cfg.backend by every dispatch site
+    (core.windowing, core.genasm)."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    targets = GPU_PLATFORMS if backend == "pallas_gpu" else ("tpu",)
+    if platform not in targets:
+        raise ValueError(
+            f"backend={backend!r} has no lowering for platform "
+            f"{platform!r} (it compiles for {'/'.join(targets)}); choose "
+            f"a backend that targets {platform!r} or 'jnp'")
+    return False
 
 
 def _pad_to_tile(pat_codes, text_codes, tile):
@@ -104,7 +116,7 @@ def _pair_specs(mesh, ranks_in, ranks_out):
 
 @partial(jax.jit, static_argnames=("cfg", "tile", "interpret", "mesh"))
 def genasm_dc_op(pat_codes, text_codes, *, cfg: AlignerConfig,
-                 tile: int | None = None, interpret: bool = True, mesh=None):
+                 interpret: bool, tile: int | None = None, mesh=None):
     """Standard layout in, standard layout out.
 
     pat_codes/text_codes: (B, W).  Returns DCResult-like tuple
@@ -126,7 +138,7 @@ def genasm_dc_op(pat_codes, text_codes, *, cfg: AlignerConfig,
                                    "max_steps", "tile", "interpret", "mesh"))
 def genasm_tb_fused_op(pat_codes, text_codes, *, cfg: AlignerConfig,
                        commit_limit: int, max_ops: int, max_steps: int,
-                       tile: int | None = None, interpret: bool = True,
+                       interpret: bool, tile: int | None = None,
                        mesh=None):
     """Fused GenASM-DC+TB: standard layout in, traceback dict out.
 
@@ -171,8 +183,8 @@ def _unpack_meta(ops, meta, cfg):
                                    "max_steps", "tile", "interpret", "mesh"))
 def genasm_tail_fused_op(pat_codes, text_codes, m_len, n_len, *,
                          cfg: AlignerConfig, n_text: int, commit_limit: int,
-                         max_ops: int, max_steps: int, tile: int | None = None,
-                         interpret: bool = True, mesh=None):
+                         max_ops: int, max_steps: int, interpret: bool,
+                         tile: int | None = None, mesh=None):
     """Fused rectangular-tail GenASM-DC+TB: standard layout in, traceback
     dict out (same contract as the jnp dc_jmajor + traceback mode='and'
     tail path of core.windowing, bit for bit).

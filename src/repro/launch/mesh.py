@@ -9,13 +9,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType (explicit-sharding meshes) only exists in newer
-    # jax; every mesh here is GSPMD-Auto, which is also the old default.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # every mesh here is GSPMD-Auto: shardings are constraints, not types
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,24 +25,10 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     return _mesh(shape, axes)
 
 
-def use_mesh(mesh):
-    """Context manager activating `mesh`: jax.set_mesh on new jax; on older
-    versions Mesh is itself a context manager with the same effect for
-    shard_map/collective lowering (explicit shardings don't need it)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map compat: the replication-check kwarg was renamed
-    (check_rep -> check_vma) when shard_map left jax.experimental."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """jax.shard_map with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def batch_axes(mesh) -> tuple:

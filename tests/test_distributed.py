@@ -33,7 +33,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from repro.train.step import (abstract_state, init_state,
                                       make_train_step, state_partition_specs)
         from repro.launch.dryrun import tree_shardings, batch_pspec
-        from repro.launch.mesh import make_test_mesh, use_mesh
+        from repro.launch.mesh import make_test_mesh
         from repro.data.tokens import TokenStream
 
         cfg = tiny_config(get_config('llama3.2-1b'))
@@ -52,7 +52,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         b_sh = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, batch_pspec(
                 jax.ShapeDtypeStruct(s.shape, s.dtype), mesh)), batch)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             stp = jax.jit(step, in_shardings=(st_sh, b_sh),
                           out_shardings=(st_sh, None))
             state_d = jax.device_put(state, st_sh)
@@ -131,7 +131,7 @@ def test_aligner_shards_over_mesh():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.config import AlignerConfig
         from repro.serve.align_step import make_align_step
-        from repro.launch.mesh import make_test_mesh, use_mesh
+        from repro.launch.mesh import make_test_mesh
         from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
         from repro.core.windowing import self_tail_width
 
@@ -157,7 +157,7 @@ def test_aligner_shards_over_mesh():
                 jax.device_put(jnp.array(rl), vsh),
                 jax.device_put(jnp.array(refs), bsh),
                 jax.device_put(jnp.array(fl), vsh))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, summary = stepf(*args)
         assert int(summary['n_failed']) == 0
         print('OK aligned', int(summary['total_edits']))
@@ -174,7 +174,7 @@ def test_rescued_aligner_shards_over_mesh():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.config import AlignerConfig
         from repro.serve.align_step import make_align_step_rescued
-        from repro.launch.mesh import make_test_mesh, use_mesh
+        from repro.launch.mesh import make_test_mesh
         from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
         from repro.core.windowing import rescue_schedule, self_tail_width
 
@@ -201,7 +201,7 @@ def test_rescued_aligner_shards_over_mesh():
                 jax.device_put(jnp.array(rl), vsh),
                 jax.device_put(jnp.array(refs), bsh),
                 jax.device_put(jnp.array(fl), vsh))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, summary = stepf(*args)
         ku = np.asarray(out['k_used'])
         failed = np.asarray(out['failed'])

@@ -48,7 +48,8 @@ def test_fused_bit_identical_to_jnp_band(W, k, tile, B, rng):
     pat, txt = jnp.array(pats), jnp.array(txts)
     res, tb = jnp_band_tb(pat, txt, cfg, stride, max_ops, max_steps)
     fz = genasm_tb_fused_op(pat, txt, cfg=cfg, commit_limit=stride,
-                            max_ops=max_ops, max_steps=max_steps, tile=tile)
+                            max_ops=max_ops, max_steps=max_steps, tile=tile,
+                            interpret=True)
     assert (np.array(fz["dist"]) == np.array(res.dist)).all()
     assert int(fz["levels"]) == int(res.levels_run)
     assert bool(np.array(fz["ok"]).all())
@@ -67,7 +68,7 @@ def test_fused_cigars_optimal_vs_oracle(rng):
     pats, txts, eds = batch(rng, W, k, B)
     fz = genasm_tb_fused_op(jnp.array(pats), jnp.array(txts), cfg=cfg,
                             commit_limit=10**6, max_ops=max_ops,
-                            max_steps=max_steps, tile=4)
+                            max_steps=max_steps, tile=4, interpret=True)
     assert bool(np.array(fz["ok"]).all())
     n_solved = 0
     for b in range(B):
@@ -106,7 +107,8 @@ def test_fused_matches_all_jnp_store_modes_committed(rng):
             if eds[b] <= k else None for b in range(B)]
     cfg = AlignerConfig(W=W, O=W // 3, k=k)
     fz = genasm_tb_fused_op(pat, txt, cfg=cfg, commit_limit=stride,
-                            max_ops=max_ops, max_steps=max_steps, tile=8)
+                            max_ops=max_ops, max_steps=max_steps, tile=8,
+                            interpret=True)
     committed["fused"] = [
         ops_to_string(np.array(fz["ops"])[b][:int(fz["n_ops"][b])])
         if eds[b] <= k else None for b in range(B)]
@@ -137,10 +139,11 @@ def test_fused_windowed_alignment_matches_jnp(rng):
     assert res_j.cigars == res_f.cigars
 
 
-def _kernel_call(pats, txts, cfg, **kw):
+def _kernel_call(pats, txts, cfg, interpret=True, **kw):
     return genasm_tb_fused_op(jnp.array(pats), jnp.array(txts), cfg=cfg,
                               commit_limit=cfg.stride, max_ops=cfg.tb_max_ops,
-                              max_steps=cfg.tb_max_steps, **kw)
+                              max_steps=cfg.tb_max_steps, interpret=interpret,
+                              **kw)
 
 
 def test_fused_tile_grouping_invariance(rng):
@@ -210,7 +213,7 @@ def test_tail_banded_bit_identical_to_full_store(W, O, k, rng):
     args = (jnp.asarray(pat), jnp.asarray(txt), jnp.asarray(ml),
             jnp.asarray(nl))
     kw = dict(n_text=wt, commit_limit=2 * (W + wt), max_ops=W + wt,
-              max_steps=W + wt + 4, tile=4)
+              max_steps=W + wt + 4, tile=4, interpret=True)
     a = genasm_tail_fused_op(*args, cfg=full, **kw)
     b = genasm_tail_fused_op(*args, cfg=band, **kw)
     assert set(a) == set(b)
@@ -245,7 +248,7 @@ def test_gpu_band_as_output_bit_identical_to_scratch(rng):
     args = (jnp.asarray(pat), jnp.asarray(txt), jnp.asarray(ml),
             jnp.asarray(nl))
     kw = dict(n_text=wt, commit_limit=2 * (cfg.W + wt), max_ops=cfg.W + wt,
-              max_steps=cfg.W + wt + 4, tile=4)
+              max_steps=cfg.W + wt + 4, tile=4, interpret=True)
     for store in ("band", "full"):   # both tail stores have a GPU lowering
         ct = dataclasses.replace(cfg, tail_store=store)
         gt = dataclasses.replace(gpu, tail_store=store)
@@ -279,24 +282,15 @@ def test_gpu_compiled_parity_real_device(rng):
                                       np.array(compiled[key]), err_msg=key)
 
 
-from jax.experimental.pallas import tpu as pltpu  # noqa: E402
-
-_TPU_INTERPRET = getattr(pltpu, "force_tpu_interpret_mode", None)
-
-
-@pytest.mark.skipif(_TPU_INTERPRET is None,
-                    reason="this jax lacks pltpu.force_tpu_interpret_mode "
-                           "(added after 0.4.37) — parity runs once CI's "
-                           "jax is upgraded; see docs/backends.md")
 def test_fused_kernels_tpu_interpret_parity(rng):
-    """ROADMAP item: the fused kernel under pltpu.force_tpu_interpret_mode
-    (the TPU lowering semantics, emulated) must be bit-identical to plain
-    interpret mode, so interpret=False defaults can be flipped safely on
-    real TPUs."""
+    """The fused kernel under pltpu.force_tpu_interpret_mode (the TPU
+    lowering semantics, emulated) must be bit-identical to plain interpret
+    mode — the CPU-side check behind compiling the kernels on a TPU."""
+    from jax.experimental.pallas import tpu as pltpu
     cfg = AlignerConfig(W=16, O=6, k=4)
     pats, txts, _ = batch(rng, 16, 4, 8)
     plain = _kernel_call(pats, txts, cfg, tile=4, interpret=True)
-    with _TPU_INTERPRET():
+    with pltpu.force_tpu_interpret_mode():
         tpu_interp = _kernel_call(pats, txts, cfg, tile=4, interpret=False)
     for key in ("ops", "n_ops", "dist", "read_adv", "ref_adv", "cost"):
         np.testing.assert_array_equal(np.array(plain[key]),
@@ -323,3 +317,26 @@ def test_fused_rescue_doubles_k(rng):
                            expected_dist=res.dist[i])
     assert (res.k_used[~res.failed] >= 4).all()
     assert (res.k_used[~res.failed] > 4).any()   # at least one needed rescue
+
+
+@pytest.mark.parametrize("store", ["band", "full"])
+def test_tail_kernels_tpu_interpret_parity(store, rng):
+    """Both rectangular-tail kernels under pltpu.force_tpu_interpret_mode
+    are bit-identical to plain interpret mode on ragged tails."""
+    import dataclasses
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.ops import genasm_tail_fused_op
+    cfg = dataclasses.replace(AlignerConfig(W=32, O=10, k=9),
+                              tail_store=store)
+    wt = cfg.W + 4 * cfg.k
+    pat, txt, ml, nl = _tail_batch(rng, cfg, 6, wt)
+    args = tuple(jnp.asarray(x) for x in (pat, txt, ml, nl))
+    kw = dict(cfg=cfg, n_text=wt, commit_limit=2 * (cfg.W + wt),
+              max_ops=cfg.W + wt, max_steps=cfg.W + wt + 4, tile=4)
+    plain = genasm_tail_fused_op(*args, interpret=True, **kw)
+    with pltpu.force_tpu_interpret_mode():
+        tpu_interp = genasm_tail_fused_op(*args, interpret=False, **kw)
+    for key in plain:
+        np.testing.assert_array_equal(np.array(plain[key]),
+                                      np.array(tpu_interp[key]), err_msg=key)
+    assert bool(np.array(plain["solved"]).any())
