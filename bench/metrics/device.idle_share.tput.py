@@ -1,0 +1,10 @@
+"""device.idle_share.tput: share of the traced window in which no
+operation ran on the device, mean over the chips used, in percent
+(profiler trace); read in the throughput cells."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
