@@ -1,0 +1,18 @@
+"""retire.fetch_us_per_read: time inside the session's ``retire.fetch``
+spans during the window, per request answered in it (microseconds, host
+clock), clipped to the window as ``session.host_us_per_read`` is.  The
+span is the download of a dispatch's outputs (``transfer.to_host``),
+which waits for the device to finish the dispatch.  A program without the
+span reports nothing."""
+
+SPAN = "retire.fetch"
+
+
+def read(run):
+    spans = [s for s in run.spans if s["name"] == SPAN]
+    n = int(run.answered_in(run.t0, run.t1).sum())
+    if not spans or n == 0:
+        return None
+    busy = sum(max(0.0, min(s["t1"], run.t1) - max(s["t0"], run.t0))
+               for s in spans)
+    return 1e6 * busy / n

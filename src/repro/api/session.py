@@ -68,7 +68,8 @@ from ..core.aligner import AlignResult
 from ..core.cigar import decode_batch, records_from_state
 from ..core.config import AlignerConfig, resolve_config
 from ..core.windowing import (SENTINEL_READ, SENTINEL_REF, bucket_avals,
-                              pad_geometry, pow2_bucket, rescue_schedule)
+                              n_main_windows, pad_geometry, pow2_bucket,
+                              rescue_schedule)
 from ..distributed.sharding import (bucket_lanes, lane_classes,
                                     mesh_fingerprint)
 from ..obs import MetricsRegistry, default_registry, resolve_obs
@@ -517,9 +518,15 @@ class _Dispatch:
     reads: list            # n_real host code arrays (for bucket rescue)
     refs: list
     out: dict              # device arrays (async) from the executable
+    seq: int               # the session's dispatch number (span attribute)
+    lanes: int             # lanes launched, padding included
 
 
 _SHUTDOWN = object()       # retire-queue sentinel for close()
+
+#: per-lane outputs every retire downloads
+_OUT_KEYS = ("ops", "n_ops", "dist", "failed", "read_consumed",
+             "ref_consumed")
 
 
 # --------------------------------------------------------------------------
@@ -551,6 +558,8 @@ class AlignSession:
         "callback_errors": "session_callback_errors_total",
         "wall_s": "session_wall_seconds_total",
         "retire_wall_s": "session_retire_wall_seconds_total",
+        "lane_windows": "session_lane_windows_total",
+        "useful_lane_windows": "session_useful_lane_windows_total",
     }
 
     def __init__(self, spec: AlignSpec, cache: CompileCache | str = "shared",
@@ -566,6 +575,9 @@ class AlignSession:
         # locked += per event (or a no-op call when obs='off')
         self._m = {k: self.obs.counter(name)
                    for k, name in self.STAT_METRICS.items()}
+        self._n_dispatched = 0     # numbers the dispatches for their spans
+        # the lane-window counters' extra download; none under obs='off'
+        self._count_keys = ("window_steps",) if self.obs.enabled else ()
         if cache == "shared":
             store = _PROCESS_CACHE
         elif cache == "private":
@@ -879,8 +891,10 @@ class AlignSession:
         refs = [it[2] for it in items]
         rb, fb = bucket
         lanes = bucket_lanes(len(items), self.cfg, self.mesh)
+        self._n_dispatched += 1
+        seq = self._n_dispatched
         with self.obs.span("session.dispatch", bucket=f"{rb}x{fb}",
-                           lanes=lanes, n_real=len(items)):
+                           lanes=lanes, n_real=len(items), dispatch=seq):
             device_mode = self.spec.rescue_mode == "device"
             rounds = self.spec.rescue_rounds if device_mode else None
             exe = self._executable(self.cfg, lanes, rb, fb,
@@ -888,11 +902,11 @@ class AlignSession:
             Lr, Lf = pad_geometry(self.cfg, rb, fb, rounds or 0)
             dev = transfer.to_device(
                 self._pad_batch(reads, refs, lanes, Lr, Lf))
-            # the launch is async under jax dispatch: this span covers
-            # upload + enqueue, not device occupancy
-            with self.obs.span("device.execute", lanes=lanes):
+            # the upload is done: under jax's async dispatch this span
+            # covers the enqueue only, not device occupancy
+            with self.obs.span("device.execute", lanes=lanes, dispatch=seq):
                 out, _ = exe(*dev)
-        d = _Dispatch(futs, reads, refs, out)
+        d = _Dispatch(futs, reads, refs, out, seq, lanes)
         if threaded:
             self._enqueue_retire(d)
         else:
@@ -1004,24 +1018,47 @@ class AlignSession:
     def _retire(self, d: _Dispatch):
         """Force one dispatch: download once, decode via the off-thread
         entrypoint (core.cigar), run compacted bucket-rescue rounds if
-        needed, fulfill futures."""
+        needed, fulfill futures.  Inside ``retire.decode`` one child span
+        per cost: ``retire.fetch`` (the download, which waits for the
+        device), ``retire.records`` (decode and records; opened on each
+        side of the ``rescue.rung`` spans when bucket rescue runs) and
+        ``retire.fulfill`` (futures, with the gateway's and clients'
+        callbacks)."""
         t0 = self._clock()
         n = len(d.futures)
-        with self.obs.span("retire.decode", n=n):
-            keys = ("ops", "n_ops", "dist", "failed", "read_consumed",
-                    "ref_consumed") + (("k_used",)
-                                       if "k_used" in d.out else ())
-            host = transfer.to_host({k: d.out[k] for k in keys})
-            failed, dist, k_used, rcon, fcon, all_ops = \
-                decode_batch(host, n, self.cfg.k)
-            if self.spec.rescue_mode == "bucket" and failed.any():
-                self._rescue_compacted(d, failed, dist, k_used, rcon, fcon,
-                                       all_ops)
-            recs = records_from_state(failed, dist, k_used, rcon, fcon,
-                                      all_ops)
-            for fut, rec in zip(d.futures, recs):
-                fut._fulfill(rec)
+        span = self.obs.span
+        with span("retire.decode", n=n, dispatch=d.seq):
+            keys = _OUT_KEYS + (("k_used",) if "k_used" in d.out else ()) \
+                + self._count_keys
+            with span("retire.fetch"):
+                host = transfer.to_host({k: d.out[k] for k in keys})
+            with span("retire.records"):
+                state = decode_batch(host, n, self.cfg.k)
+                failed = state[0]      # bucket rescue updates it in place
+                rescue = self.spec.rescue_mode == "bucket" and failed.any()
+                if not rescue:
+                    recs = records_from_state(*state)
+            if rescue:
+                self._rescue_compacted(d, *state)
+                with span("retire.records"):
+                    recs = records_from_state(*state)
+            if self._count_keys:
+                self._count_lane_windows(d, host["window_steps"], failed)
+            with span("retire.fulfill"):
+                for fut, rec in zip(d.futures, recs):
+                    fut._fulfill(rec)
         self._m["retire_wall_s"].inc(self._clock() - t0)
+
+    def _count_lane_windows(self, d: _Dispatch, window_steps, failed):
+        """Lane-windows the device ran for `d` (its lanes times the window
+        steps its step reports) against those whose ops are in an answer:
+        a solved real lane needs n_main_windows(read length) + 1 windows,
+        since every window commits W - O read characters.  Rescue rungs
+        add their own lanes x steps in _rescue_compacted."""
+        self._m["lane_windows"].inc(d.lanes * int(window_steps))
+        self._m["useful_lane_windows"].inc(sum(
+            n_main_windows(len(r), self.cfg) + 1
+            for r, bad in zip(d.reads, failed) if not bad))
 
     def _rescue_compacted(self, d, failed, dist, k_used, rcon, fcon,
                           all_ops):
@@ -1051,10 +1088,11 @@ class AlignSession:
                     self._pad_batch(reads, refs, lanes, Lr, Lf))
                 out, _ = exe(*dev)
                 host = transfer.to_host(
-                    {k: out[k] for k in ("ops", "n_ops", "dist", "failed",
-                                         "read_consumed", "ref_consumed")})
+                    {k: out[k] for k in _OUT_KEYS + self._count_keys})
             self._m["rescue_dispatches"].inc()
             self._m["rescue_lanes"].inc(lanes)
+            if self._count_keys:
+                self._m["lane_windows"].inc(lanes * int(host["window_steps"]))
             ok = ~np.asarray(host["failed"])
             for loc, glob in enumerate(todo):
                 if ok[loc]:

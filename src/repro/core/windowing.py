@@ -178,7 +178,9 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     reads: (B, Lr_pad) uint8 codes, sentinel-padded by >= W past read_len.
     refs:  (B, Lf_pad) uint8 codes, sentinel-padded by >= W+4k past ref_len.
     Returns dict with front-first op buffer, n_ops, dist, failed, read/ref
-    consumption, and window ET stats.
+    consumption, window ET stats and ``window_steps`` (main-scan steps + 1
+    tail, every lane runs them).  The scan body runs under the named scope
+    ``window_step`` and the tail under ``tail_window``.
 
     `mesh`: shard the pair axis over the mesh's data axes — the Pallas
     dispatches run under shard_map (each device fills/walks its local
@@ -201,6 +203,7 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     read_len = jnp.asarray(read_len, jnp.int32)
     ref_len = jnp.asarray(ref_len, jnp.int32)
 
+    @jax.named_scope("window_step")
     def append_main(carry, _):
         (read_pos, ref_pos, off, dist, failed, levels), buf = carry
         active = (read_len - read_pos > W) & ~failed
@@ -246,42 +249,46 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     read_pos, ref_pos, off, dist, failed, levels = state
 
     # ---- tail window: remaining read (in (O, W]) vs remaining ref, global ----
-    m_tail = jnp.clip(read_len - read_pos, 0, W)
-    n_rem = ref_len - ref_pos
-    n_tail = jnp.clip(n_rem, 0, wt)
-    tail_bad = (n_rem > wt) | (n_rem < jnp.maximum(m_tail - 2 * k, 0))
-    pat_t = _slice_rev(reads, read_pos, W, m_tail)
-    txt_t = _slice_rev(refs, ref_pos, wt, n_tail)
-    if cfg.store == "band" and cfg.backend in ("pallas_fused", "pallas_gpu"):
-        # rectangular-tail fused kernel: the tail's SENE store is walked
-        # on-chip too, so whole-read alignment never ships DP state to
-        # HBM (bit-identical to the jnp 'and'-store path below)
-        from ..kernels.ops import default_interpret, genasm_tail_fused_op
-        tb_t = genasm_tail_fused_op(pat_t, txt_t, m_tail, n_tail, cfg=cfg,
-                                    n_text=wt, commit_limit=2 * (W + wt),
-                                    max_ops=max_ops_t, max_steps=max_steps_t,
-                                    interpret=default_interpret(cfg.backend),
-                                    mesh=mesh)
-        solved_t = tb_t["solved"]
-    else:
-        res_t = dc_jmajor(pat_t, txt_t, m_tail, n_tail, k=k, n=wt, nw=cfg.nw,
-                          store="and")
-        tb_t = traceback(res_t.store, pat_t, txt_t, m_tail, n_tail, res_t.dist,
-                         jnp.int32(2 * (W + wt)), cfg=cfg, mode="and",
-                         max_ops=max_ops_t, max_steps=max_steps_t)
-        solved_t = res_t.solved
-    t_ok = ~failed & ~tail_bad & solved_t
-    buf = _append_ops(buf, off, tb_t["ops"], jnp.where(t_ok, tb_t["n_ops"], 0),
-                      t_ok)
-    n_ops = jnp.where(t_ok, off + tb_t["n_ops"], off)
-    dist = jnp.where(t_ok, dist + tb_t["cost"], dist)
-    failed = failed | tail_bad | ~solved_t
-    read_end = jnp.where(t_ok, read_pos + tb_t["read_adv"], read_pos)
-    ref_end = jnp.where(t_ok, ref_pos + tb_t["ref_adv"], ref_pos)
+    with jax.named_scope("tail_window"):
+        m_tail = jnp.clip(read_len - read_pos, 0, W)
+        n_rem = ref_len - ref_pos
+        n_tail = jnp.clip(n_rem, 0, wt)
+        tail_bad = (n_rem > wt) | (n_rem < jnp.maximum(m_tail - 2 * k, 0))
+        pat_t = _slice_rev(reads, read_pos, W, m_tail)
+        txt_t = _slice_rev(refs, ref_pos, wt, n_tail)
+        if cfg.store == "band" and cfg.backend in ("pallas_fused",
+                                                   "pallas_gpu"):
+            # rectangular-tail fused kernel: the tail's SENE store is walked
+            # on-chip too, so whole-read alignment never ships DP state to
+            # HBM (bit-identical to the jnp 'and'-store path below)
+            from ..kernels.ops import default_interpret, genasm_tail_fused_op
+            tb_t = genasm_tail_fused_op(
+                pat_t, txt_t, m_tail, n_tail, cfg=cfg, n_text=wt,
+                commit_limit=2 * (W + wt), max_ops=max_ops_t,
+                max_steps=max_steps_t,
+                interpret=default_interpret(cfg.backend), mesh=mesh)
+            solved_t = tb_t["solved"]
+        else:
+            res_t = dc_jmajor(pat_t, txt_t, m_tail, n_tail, k=k, n=wt,
+                              nw=cfg.nw, store="and")
+            tb_t = traceback(res_t.store, pat_t, txt_t, m_tail, n_tail,
+                             res_t.dist, jnp.int32(2 * (W + wt)), cfg=cfg,
+                             mode="and", max_ops=max_ops_t,
+                             max_steps=max_steps_t)
+            solved_t = res_t.solved
+        t_ok = ~failed & ~tail_bad & solved_t
+        buf = _append_ops(buf, off, tb_t["ops"],
+                          jnp.where(t_ok, tb_t["n_ops"], 0), t_ok)
+        n_ops = jnp.where(t_ok, off + tb_t["n_ops"], off)
+        dist = jnp.where(t_ok, dist + tb_t["cost"], dist)
+        failed = failed | tail_bad | ~solved_t
+        read_end = jnp.where(t_ok, read_pos + tb_t["read_adv"], read_pos)
+        ref_end = jnp.where(t_ok, ref_pos + tb_t["ref_adv"], ref_pos)
 
     return {"ops": buf, "n_ops": n_ops, "dist": dist, "failed": failed,
             "read_consumed": read_end, "ref_consumed": ref_end,
-            "levels_run_total": levels, "n_main_windows": jnp.int32(nm)}
+            "levels_run_total": levels, "n_main_windows": jnp.int32(nm),
+            "window_steps": jnp.int32(nm + 1)}
 
 
 def rescue_schedule(cfg: AlignerConfig, rescue_rounds: int):
@@ -318,12 +325,15 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     batch with doubled k under a ``lax.cond`` gate (skipped outright when no
     lane is still failed), and a per-lane mask freezes already-solved lanes
     so their ops/dist/k_used never change — bit-identical per lane to the
-    host numpy rescue loop in core.aligner.
+    host numpy rescue loop in core.aligner.  Each round runs under the
+    named scope ``rung_k<k>``, so its operators carry the rung in a device
+    trace's ``op_name``.
 
     refs must be sentinel-padded for the FINAL round's tail width
     (``self_tail_width(rescue_schedule(cfg, rescue_rounds)[-1])``); reads
     need the usual >= W padding.  Returns the align_pairs dict plus k_used
-    (0 where never solved), rounds_run and n_rounds.
+    (0 where never solved), rounds_run and n_rounds; ``window_steps`` sums
+    the window steps (main scan + tail) of the rounds that ran.
 
     `mesh` threads through to every round's align_pairs: the whole ladder
     runs sharded over the pair axes, and the `any(failed)` round gate is a
@@ -343,11 +353,13 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     failed = jnp.ones((B,), bool)
     levels = jnp.int32(0)
     rounds_run = jnp.int32(0)
+    window_steps = jnp.int32(0)
 
     for rnd, cfg_r in enumerate(cfgs):
         def run_round(cfg_r=cfg_r):
-            return align_pairs(reads, read_len, refs, ref_len, cfg=cfg_r,
-                               max_read_len=max_read_len, mesh=mesh)
+            with jax.named_scope(f"rung_k{cfg_r.k}"):
+                return align_pairs(reads, read_len, refs, ref_len, cfg=cfg_r,
+                                   max_read_len=max_read_len, mesh=mesh)
         if rnd == 0:
             out = run_round()
             ran = jnp.bool_(True)
@@ -380,8 +392,9 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
         failed = failed & out["failed"]
         levels = levels + out["levels_run_total"]
         rounds_run = rounds_run + ran.astype(jnp.int32)
+        window_steps = window_steps + out["window_steps"]  # 0 when skipped
 
     return {"ops": ops, "n_ops": n_ops, "dist": dist, "failed": failed,
             "k_used": k_used, "read_consumed": rcon, "ref_consumed": fcon,
             "levels_run_total": levels, "rounds_run": rounds_run,
-            "n_rounds": jnp.int32(len(cfgs))}
+            "n_rounds": jnp.int32(len(cfgs)), "window_steps": window_steps}

@@ -88,6 +88,13 @@ META_DIST, META_LVL, META_NOPS, META_RD, META_RF, META_DFIN, META_OK = range(7)
 META_ROWS = 8
 
 
+def kernel_name(kind: str, cfg: AlignerConfig) -> str:
+    """The kernel's stable name, stating its rung's k ('genasm_tb_k24').
+    Pallas hands it on as the HLO instruction name, which is what a
+    device trace's op line shows (with XLA's '.N' suffix)."""
+    return f"{kind}_k{cfg.k}"
+
+
 def _band_base(j, k, m_pad, nwb):
     lo = j - 2 - k
     hi = m_pad - WORD * nwb
@@ -569,6 +576,7 @@ def genasm_dc_pallas(pm, text, *, cfg: AlignerConfig, tile: int = 128,
     out = pl.pallas_call(
         kern,
         grid=grid,
+        name=kernel_name("genasm_dc", cfg),
         in_specs=[
             pl.BlockSpec((5, nw, tile), lambda i: (0, 0, i)),
             pl.BlockSpec((W, tile), lambda i: (0, i)),
@@ -632,6 +640,7 @@ def genasm_tb_fused_pallas(pm, text, *, cfg: AlignerConfig, commit_limit: int,
     out = pl.pallas_call(
         kern,
         grid=grid,
+        name=kernel_name("genasm_tb", cfg),
         in_specs=[
             pl.BlockSpec((5, nw, tile), lambda i: (0, 0, i)),
             pl.BlockSpec((W, tile), lambda i: (0, i)),
@@ -897,6 +906,7 @@ def genasm_tail_fused_pallas(pm, text, m_len, n_len, *, cfg: AlignerConfig,
     out = pl.pallas_call(
         kern,
         grid=grid,
+        name=kernel_name("genasm_tail", cfg),
         in_specs=[
             pl.BlockSpec((5, nw, tile), lambda i: (0, 0, i)),
             pl.BlockSpec((n_text, tile), lambda i: (0, i)),

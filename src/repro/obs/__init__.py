@@ -9,7 +9,8 @@ from per-stage accounting; so do we):
   plus injectable per-session registries.
 * :mod:`repro.obs.trace` — structured span tracing on an injectable
   clock (``gateway.admit → session.dispatch → device.execute``,
-  ``retire.decode → rescue.rung``, and the mapper funnel
+  ``retire.decode → retire.fetch / retire.records / rescue.rung /
+  retire.fulfill``, and the mapper funnel
   ``index.lookup → chain → prefilter → align``).
 * :mod:`repro.obs.export` — Prometheus text, JSON-lines, perfetto
   trace-event JSON.

@@ -5,7 +5,8 @@ thread (a span opened while another is live on the same thread records
 it as its parent), so the serving stack's hierarchy —
 
     gateway.admit -> session.dispatch -> device.execute
-    retire.decode -> rescue.rung[k]
+    retire.decode -> retire.fetch / retire.records / rescue.rung[k] /
+                     retire.fulfill
     mapper.map_batch -> index.lookup / chain / prefilter / align
 
 — falls out of the ``with tracer.span(...)`` blocks already wrapping

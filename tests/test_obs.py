@@ -18,6 +18,8 @@ exporters) and its contract with the serving stack:
   acceptance criterion;
 * the EXACT span tree of a 2-bucket ragged batch with one rescue rung,
   on a fake clock;
+* the lane-window counters against closed forms (padding lanes, rescue
+  rungs, obs='off');
 * the done-callback regression: a raising callback (even a
   BaseException) must be swallowed-and-recorded, never poison the
   session (pre-PR code let it unwind into the retire path).
@@ -34,6 +36,7 @@ import repro.obs
 from repro.api import AlignSession, CompileCache, Gateway, GatewayPolicy, plan
 from repro.core import transfer
 from repro.core.config import AlignerConfig
+from repro.core.windowing import n_main_windows
 from repro.obs import (DEFAULT_EDGES, MetricsRegistry, NULL_METRIC,
                        NULL_REGISTRY, NULL_SPAN, NULL_TRACER, OBS_OFF, Obs,
                        Tracer, default_registry, perfetto_trace,
@@ -362,6 +365,61 @@ def test_session_and_cache_view_families_match_registry():
         assert s.cache.shared_hits == snap["session_cache_shared_hits_total"]
 
 
+def _lane_window_pairs():
+    """Four pairs of one 32x32 bucket: exact matches of 20 and 30 bp, a
+    30 bp read with three substitutions in its first window (k = 2 fails
+    it, the k = 4 rung solves it) and an unrelated 25 bp decoy that fails
+    every rung."""
+    rng = np.random.default_rng(5)
+    mk = lambda n: rng.integers(0, 4, n).astype(np.uint8)  # noqa: E731
+    a, b, c = mk(20), mk(30), mk(30)
+    c_read = c.copy()
+    c_read[[2, 6, 10]] = (c_read[[2, 6, 10]] + 1) % 4
+    return [a, b, c_read, mk(25)], [a.copy(), b.copy(), c, mk(25)]
+
+
+def _windows(read_len):
+    return n_main_windows(read_len, CFG) + 1
+
+
+@pytest.mark.parametrize("rescue_mode", ["bucket", "device"])
+def test_lane_window_counters_closed_forms(rescue_mode):
+    """session_lane_windows_total counts lanes x window steps the device
+    ran (padding lanes and every rung included); the useful count is
+    sum(n_main_windows(len) + 1) over the real lanes that were solved,
+    whichever rung solved them.  Closed forms at tiny sizes on the CPU;
+    under obs='off' both read 0."""
+    reads, refs = _lane_window_pairs()
+    step = _windows(32)                 # the 32 bucket's scan + tail
+    kw = dict(PLAN_KW, rescue_mode=rescue_mode)
+    with plan(CFG, **kw) as s:
+        # exact pairs of several lengths, 3 real lanes in a 4-lane class:
+        # the padding lane adds to lane_windows only
+        s.align([reads[0], reads[1], reads[1]], [refs[0], refs[1], refs[1]])
+        assert s.stats["lanes"] == 4 and s.stats["pad_lanes"] == 1
+        assert s.stats["lane_windows"] == 4 * step
+        assert s.stats["useful_lane_windows"] == \
+            _windows(20) + 2 * _windows(30)
+        s0 = dict(s.stats)
+        res = s.align(reads, refs)
+        assert res.failed.tolist() == [False, False, False, True]
+        assert res.k_used[:3].tolist() == [2, 2, 4]
+        lw = s.stats["lane_windows"] - s0["lane_windows"]
+        useful = s.stats["useful_lane_windows"] - s0["useful_lane_windows"]
+    if rescue_mode == "bucket":
+        # the k = 4 rung compacts the two failed lanes into a 2-lane class
+        assert lw == 4 * step + 2 * step
+    else:
+        # the on-device ladder reruns every lane at k = 4
+        assert lw == 4 * 2 * step
+    # the rung adds only the lane it solved; the decoy adds nothing
+    assert useful == _windows(20) + 2 * _windows(30)
+    with plan(CFG, **kw, obs="off") as s:
+        s.align(reads, refs)
+        assert s.stats["lane_windows"] == 0
+        assert s.stats["useful_lane_windows"] == 0
+
+
 def test_gateway_family_matches_registry():
     clk = FakeClock()
     s = plan(CFG, rescue_rounds=0, batch_lanes=4, clock=clk)
@@ -431,7 +489,10 @@ def test_mapper_funnel_matches_registry_deltas():
 def test_session_trace_exact_span_tree():
     """2-bucket ragged batch, one rescue rung, sync executor, FakeClock:
     the complete trace is byte-stable — exact names, nesting, attrs and
-    (never-advanced) timestamps."""
+    (never-advanced) timestamps.  Each retire.decode holds one child span
+    per cost (fetch, records, fulfill); records opens on each side of the
+    rescue rung; a dispatch-side span and its retire span share the
+    ``dispatch`` attribute."""
     clk = FakeClock()
     reads, refs = _corpus()
     with plan(CFG, **PLAN_KW, clock=clk) as s:
@@ -441,24 +502,40 @@ def test_session_trace_exact_span_tree():
     assert [r["name"] for r in recs] == [
         "device.execute", "session.dispatch",   # bucket 32x32 (4 lanes)
         "device.execute", "session.dispatch",   # bucket 128x128 (flush)
-        "rescue.rung", "retire.decode",         # decoy forces one rung
-        "retire.decode",
+        "retire.fetch", "retire.records",       # decoy forces one rung
+        "rescue.rung", "retire.records", "retire.fulfill", "retire.decode",
+        "retire.fetch", "retire.records", "retire.fulfill", "retire.decode",
     ]
-    exe_a, disp_a, exe_b, disp_b, rung, ret_a, ret_b = recs
-    assert disp_a["attrs"] == {"bucket": "32x32", "lanes": 4, "n_real": 4}
-    assert disp_b["attrs"] == {"bucket": "128x128", "lanes": 2, "n_real": 2}
+    (exe_a, disp_a, exe_b, disp_b, fetch_a, rec_a0, rung, rec_a1, ful_a,
+     ret_a, fetch_b, rec_b, ful_b, ret_b) = recs
+    assert disp_a["attrs"] == {"bucket": "32x32", "lanes": 4, "n_real": 4,
+                               "dispatch": 1}
+    assert disp_b["attrs"] == {"bucket": "128x128", "lanes": 2, "n_real": 2,
+                               "dispatch": 2}
+    assert exe_a["attrs"] == {"lanes": 4, "dispatch": 1}
+    assert exe_b["attrs"] == {"lanes": 2, "dispatch": 2}
     assert exe_a["parent"] == disp_a["sid"] and disp_a["parent"] is None
     assert exe_b["parent"] == disp_b["sid"] and disp_b["parent"] is None
     assert rung["attrs"] == {"k": 4, "lanes": 1, "n_todo": 1}
-    assert rung["parent"] == ret_a["sid"] and ret_a["parent"] is None
-    assert ret_a["attrs"] == {"n": 4} and ret_b["attrs"] == {"n": 2}
-    assert ret_b["parent"] is None
+    assert ret_a["attrs"] == {"n": 4, "dispatch": 1}
+    assert ret_b["attrs"] == {"n": 2, "dispatch": 2}
+    assert ret_a["parent"] is None and ret_b["parent"] is None
+    for child in (fetch_a, rec_a0, rung, rec_a1, ful_a):
+        assert child["parent"] == ret_a["sid"], child["name"]
+    for child in (fetch_b, rec_b, ful_b):
+        assert child["parent"] == ret_b["sid"], child["name"]
+    for child in (fetch_a, rec_a0, rec_a1, ful_a, fetch_b, rec_b, ful_b):
+        assert child["attrs"] == {}, child["name"]
     # FakeClock never advanced: every timestamp is exactly 0.0, and the
     # whole trace ran on this thread (sync executor)
     assert {r["t0"] for r in recs} == {0.0} and {r["t1"] for r in recs} == {0.0}
     assert {r["thread"] for r in recs} == {threading.current_thread().name}
-    # sids are allocated in OPEN order (dispatch before its child)
+    # sids are allocated in OPEN order (parent before its children, the
+    # children in the order they ran)
     assert disp_a["sid"] < exe_a["sid"] < disp_b["sid"] < exe_b["sid"]
+    assert ret_a["sid"] < fetch_a["sid"] < rec_a0["sid"] < rung["sid"] \
+        < rec_a1["sid"] < ful_a["sid"] < ret_b["sid"] < fetch_b["sid"] \
+        < rec_b["sid"] < ful_b["sid"]
 
 
 # --------------------------------------------------------------------------

@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import AlignerConfig, resolve_config
-from repro.core.windowing import (plan_lane_tile, rescue_schedule,
-                                  self_tail_width)
+from repro.core.windowing import (bucket_avals, plan_lane_tile,
+                                  rescue_schedule, self_tail_width)
 from repro.kernels.genasm_dc import (genasm_dc_pallas,
                                      genasm_tail_fused_pallas,
                                      genasm_tb_fused_pallas)
@@ -149,3 +149,40 @@ def test_rescue_rung_kernels_compile_at_their_tile(rung, one_chip):
     assert cfg.lane_tile <= plan_lane_tile(cfg) < base.lane_tile
     assert "tpu_custom_call" in _square(cfg, cfg.lane_tile, one_chip, "fused")
     assert "tpu_custom_call" in _tail(cfg, cfg.lane_tile, one_chip)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Dispatch sites ask ``default_interpret``, which answers True on
+    this CPU: make them compile the kernels.  The jit trace caches hold
+    traces from either side, so they are cleared before and after."""
+    import repro.kernels.ops as kops
+    monkeypatch.setattr(kops, "default_interpret", lambda backend: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_served_step_names_kernels_by_rung(compiled_kernels, one_chip):
+    """The served step (the on-device ladder at rescue_rounds=1, k = 12
+    and 24) compiled for the chip names each kernel by its rung: the
+    device trace's op line shows HLO instruction names, and a ledger
+    breakdown compares them across changes.  The rung's named scope
+    reaches the operators' op_name."""
+    import re
+    from repro.serve.align_step import make_align_step
+    rb = 128                            # two main windows and the tail
+    step = make_align_step(CFG, rb, None, rescue_rounds=1)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in bucket_avals(CFG, CFG.lane_tile, rb, rb, 1)]
+    text = step.lower(*args).compile().as_text()
+    kernels = {m.group(1) for m in re.finditer(
+        r"%(\S+)\.\d+ = .*custom_call_target=\"tpu_custom_call\"", text)}
+    assert kernels == {"genasm_tb_k12", "genasm_tb_k24", "genasm_tail_k12",
+                       "genasm_tail_k24"}
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/rung_k24/" in n and "/window_step/" in n
+               and n.endswith("/genasm_tb_k24/pallas_call") for n in op_names)
+    assert any("/rung_k12/" in n and "/tail_window/" in n
+               and n.endswith("/genasm_tail_k12/pallas_call")
+               for n in op_names)
