@@ -7,9 +7,12 @@ worth of operations, advance read by exactly W-O and ref by the committed
 ref consumption, repeat.  The final <= W read chars are aligned in a single
 "tail" window against the remaining reference (end-to-end).
 
-All problems advance in lockstep (read stride is uniform); problems whose
-window edit distance exceeds k are flagged `failed` (callers may rescue by
-re-running those pairs with a larger k, see core.aligner).
+All problems advance in lockstep (read stride is uniform), and the window
+loop ends at the step after which no problem is active: every read has at
+most W characters left or has failed.  The length class's window count is
+only the loop's upper bound.  Problems whose window edit distance exceeds
+k are flagged `failed` (callers may rescue by re-running those pairs with
+a larger k, see core.aligner).
 """
 from __future__ import annotations
 
@@ -178,9 +181,13 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     reads: (B, Lr_pad) uint8 codes, sentinel-padded by >= W past read_len.
     refs:  (B, Lf_pad) uint8 codes, sentinel-padded by >= W+4k past ref_len.
     Returns dict with front-first op buffer, n_ops, dist, failed, read/ref
-    consumption, window ET stats and ``window_steps`` (main-scan steps + 1
-    tail, every lane runs them).  The scan body runs under the named scope
-    ``window_step`` and the tail under ``tail_window``.
+    consumption, window ET stats and ``window_steps`` (main-window steps
+    the loop ran + 1 tail, every lane runs them).  The main-window loop
+    ends at the last active lane: once every read has <= W characters left
+    or has failed, a step would commit nothing, so none runs;
+    ``n_main_windows(max_read_len)`` only bounds it.  The loop body runs
+    under the named scope ``window_step`` and the tail under
+    ``tail_window``.
 
     `mesh`: shard the pair axis over the mesh's data axes — the Pallas
     dispatches run under shard_map (each device fills/walks its local
@@ -203,10 +210,17 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     read_len = jnp.asarray(read_len, jnp.int32)
     ref_len = jnp.asarray(ref_len, jnp.int32)
 
+    def active_lanes(read_pos, failed):
+        return (read_len - read_pos > W) & ~failed
+
+    def any_active(carry):
+        (step, read_pos, _, _, _, failed, _), _ = carry
+        return (step < nm) & jnp.any(active_lanes(read_pos, failed))
+
     @jax.named_scope("window_step")
-    def append_main(carry, _):
-        (read_pos, ref_pos, off, dist, failed, levels), buf = carry
-        active = (read_len - read_pos > W) & ~failed
+    def append_main(carry):
+        (step, read_pos, ref_pos, off, dist, failed, levels), buf = carry
+        active = active_lanes(read_pos, failed)
         wfull = jnp.full((B,), W, jnp.int32)
         pat = _slice_rev(reads, read_pos, W, wfull)
         txt = _slice_rev(refs, ref_pos, W, wfull)
@@ -232,6 +246,7 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
         buf = _append_ops(buf, off, tb["ops"], jnp.where(commit, tb["n_ops"], 0),
                           commit)
         st = (
+            step + 1,
             jnp.where(commit, read_pos + tb["read_adv"], read_pos),
             jnp.where(commit, ref_pos + tb["ref_adv"], ref_pos),
             jnp.where(commit, off + tb["n_ops"], off),
@@ -239,14 +254,14 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
             failed | (active & ~solved),
             levels + levels_run,
         )
-        return (st, buf), None
+        return st, buf
 
     buf = jnp.full((B, op_budget), OP_NONE, jnp.uint8)
-    state = (jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+    state = (jnp.int32(0), jnp.zeros((B,), jnp.int32),
              jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-             jnp.zeros((B,), bool), jnp.int32(0))
-    (state, buf), _ = jax.lax.scan(append_main, (state, buf), None, length=nm)
-    read_pos, ref_pos, off, dist, failed, levels = state
+             jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool), jnp.int32(0))
+    state, buf = jax.lax.while_loop(any_active, append_main, (state, buf))
+    steps, read_pos, ref_pos, off, dist, failed, levels = state
 
     # ---- tail window: remaining read (in (O, W]) vs remaining ref, global ----
     with jax.named_scope("tail_window"):
@@ -287,8 +302,7 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
 
     return {"ops": buf, "n_ops": n_ops, "dist": dist, "failed": failed,
             "read_consumed": read_end, "ref_consumed": ref_end,
-            "levels_run_total": levels, "n_main_windows": jnp.int32(nm),
-            "window_steps": jnp.int32(nm + 1)}
+            "levels_run_total": levels, "window_steps": steps + 1}
 
 
 def rescue_schedule(cfg: AlignerConfig, rescue_rounds: int):
@@ -333,7 +347,8 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     (``self_tail_width(rescue_schedule(cfg, rescue_rounds)[-1])``); reads
     need the usual >= W padding.  Returns the align_pairs dict plus k_used
     (0 where never solved), rounds_run and n_rounds; ``window_steps`` sums
-    the window steps (main scan + tail) of the rounds that ran.
+    the window steps (main windows + tail) of the rounds that ran, each
+    round's loop ending at its own last active lane.
 
     `mesh` threads through to every round's align_pairs: the whole ladder
     runs sharded over the pair axes, and the `any(failed)` round gate is a

@@ -382,22 +382,35 @@ def _windows(read_len):
     return n_main_windows(read_len, CFG) + 1
 
 
+def _steps(*lane_steps):
+    """Window steps one rung of a dispatch runs: its loop ends at the last
+    active lane, then the tail.  `lane_steps`: each lane's main steps —
+    n_main_windows(read length) for a lane the rung solves, the index of
+    the window it fails in + 1 for one it fails."""
+    return max(lane_steps) + 1
+
+
+FIRST = 1                               # a lane failed in its first window
+
+
 @pytest.mark.parametrize("rescue_mode", ["bucket", "device"])
 def test_lane_window_counters_closed_forms(rescue_mode):
     """session_lane_windows_total counts lanes x window steps the device
-    ran (padding lanes and every rung included); the useful count is
+    ran (padding lanes and every rung included), where a rung's loop runs
+    until its last lane has finished or failed; the useful count is
     sum(n_main_windows(len) + 1) over the real lanes that were solved,
     whichever rung solved them.  Closed forms at tiny sizes on the CPU;
     under obs='off' both read 0."""
     reads, refs = _lane_window_pairs()
-    step = _windows(32)                 # the 32 bucket's scan + tail
+    main = lambda n: n_main_windows(n, CFG)  # noqa: E731
     kw = dict(PLAN_KW, rescue_mode=rescue_mode)
     with plan(CFG, **kw) as s:
         # exact pairs of several lengths, 3 real lanes in a 4-lane class:
-        # the padding lane adds to lane_windows only
+        # the padding lane (a repeat of the last pair) adds to lane_windows
+        # only
         s.align([reads[0], reads[1], reads[1]], [refs[0], refs[1], refs[1]])
         assert s.stats["lanes"] == 4 and s.stats["pad_lanes"] == 1
-        assert s.stats["lane_windows"] == 4 * step
+        assert s.stats["lane_windows"] == 4 * _steps(main(20), main(30))
         assert s.stats["useful_lane_windows"] == \
             _windows(20) + 2 * _windows(30)
         s0 = dict(s.stats)
@@ -406,12 +419,27 @@ def test_lane_window_counters_closed_forms(rescue_mode):
         assert res.k_used[:3].tolist() == [2, 2, 4]
         lw = s.stats["lane_windows"] - s0["lane_windows"]
         useful = s.stats["useful_lane_windows"] - s0["useful_lane_windows"]
+        s1 = dict(s.stats)
+        # a 20 bp read beside two decoys: the loop stops after the 20 bp
+        # read's one main window, short of the 32 bucket's two
+        short = s.align([reads[0], reads[3], reads[3]],
+                        [refs[0], refs[3], refs[3]])
+        assert short.failed.tolist() == [False, True, True]
+        lw_short = s.stats["lane_windows"] - s1["lane_windows"]
+    # k = 2 solves the exact pairs and fails the substituted read and the
+    # decoy in their first window
+    k2 = _steps(main(20), main(30), FIRST, FIRST)
     if rescue_mode == "bucket":
         # the k = 4 rung compacts the two failed lanes into a 2-lane class
-        assert lw == 4 * step + 2 * step
+        # and solves the substituted read; the decoy fails again at once
+        assert lw == 4 * k2 + 2 * _steps(main(30), FIRST)
+        # the two decoys alone make the k = 4 rung: both fail at once
+        assert lw_short == 4 * _steps(main(20), FIRST) + 2 * _steps(FIRST)
     else:
         # the on-device ladder reruns every lane at k = 4
-        assert lw == 4 * 2 * step
+        assert lw == 4 * (k2 + _steps(main(20), main(30), main(30), FIRST))
+        assert lw_short == 4 * 2 * _steps(main(20), FIRST)
+    assert _steps(main(20), FIRST) < _windows(32)
     # the rung adds only the lane it solved; the decoy adds nothing
     assert useful == _windows(20) + 2 * _windows(30)
     with plan(CFG, **kw, obs="off") as s:

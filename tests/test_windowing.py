@@ -3,12 +3,18 @@
 The simulated read set and the per-variant alignment results are session-
 scoped fixtures (tests/conftest.py): each aligner config is jitted and run
 once, shared by every test below."""
+import dataclasses
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.aligner import GenASMAligner
 from repro.core.config import AlignerConfig
 from repro.core.oracle import levenshtein, validate_cigar
+from repro.core.windowing import (SENTINEL_READ, SENTINEL_REF, align_pairs,
+                                  align_pairs_rescued, n_main_windows,
+                                  pad_geometry, rescue_schedule)
 from repro.data.genome import ReadSimConfig, simulate_reads, synth_genome
 
 CFG_BAND = AlignerConfig(W=64, O=24, k=12, store="band", early_term=True)
@@ -76,3 +82,148 @@ def test_decoy_pairs_fail(readset):
     al = GenASMAligner(CFG_BAND, rescue_rounds=0)
     res = al.align(reads, decoys)
     assert res.failed.all()
+
+
+# ---- the window loop ends at the last active lane -----------------------
+#
+# align_pairs bounds its main-window loop by the length class's window
+# count but stops as soon as no lane is active (every read has <= W
+# characters left or has failed).  At W=16, O=6 (stride 10) a batch of
+# reads under 50 bp in a 128 bucket would otherwise run 12 main steps.
+
+CFG_LOOP = AlignerConfig(W=16, O=6, k=2)
+BUCKET = 128
+
+
+def _loop_cfg(backend):
+    return dataclasses.replace(CFG_LOOP, backend=backend)
+
+
+def _pad_to(reads, refs, cfg, max_read_len, rescue_rounds=0):
+    """Sentinel-pad a batch for align_pairs at the length class
+    `max_read_len` (>= the longest read)."""
+    Lr, Lf = pad_geometry(cfg, max_read_len, max(len(f) for f in refs),
+                          rescue_rounds)
+    rpad = np.full((len(reads), Lr), SENTINEL_READ, np.uint8)
+    fpad = np.full((len(refs), Lf), SENTINEL_REF, np.uint8)
+    for i, (r, f) in enumerate(zip(reads, refs)):
+        rpad[i, :len(r)] = r
+        fpad[i, :len(f)] = f
+    lens = lambda xs: np.array([len(x) for x in xs], np.int32)  # noqa: E731
+    return rpad, lens(reads), fpad, lens(refs)
+
+
+def _subs(seq, at):
+    out = seq.copy()
+    out[at] = (out[at] + 1) % 4
+    return out
+
+
+def _main(read_len):
+    return n_main_windows(read_len, CFG_LOOP)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_loop_stops_at_longest_read_below_bucket(backend):
+    """Reads far shorter than their length class: the loop runs the
+    longest read's windows, not the bucket's, and every output equals the
+    exact-shape host loop's and is a valid alignment of the whole pair."""
+    cfg = _loop_cfg(backend)
+    rng = np.random.default_rng(41)
+    refs = [rng.integers(0, 4, n).astype(np.uint8) for n in (21, 28, 35, 44)]
+    # at most one substitution in any 16 bp window: k = 2 solves them all
+    reads = [_subs(f, np.arange(5, len(f), 17)) for f in refs]
+    out = align_pairs(*map(jnp.asarray, _pad_to(reads, refs, cfg, BUCKET)),
+                      cfg=cfg, max_read_len=BUCKET)
+    assert not np.asarray(out["failed"]).any()
+    assert int(out["window_steps"]) == max(map(_main, (21, 28, 35, 44))) + 1
+    assert int(out["window_steps"]) < _main(BUCKET) + 1
+    host = GenASMAligner(cfg, rescue_rounds=0,
+                         rescue_mode="host").align(reads, refs)
+    np.testing.assert_array_equal(np.asarray(out["dist"]), host.dist)
+    np.testing.assert_array_equal(np.asarray(out["read_consumed"]),
+                                  host.read_consumed)
+    np.testing.assert_array_equal(np.asarray(out["ref_consumed"]),
+                                  host.ref_consumed)
+    for i, (r, f) in enumerate(zip(reads, refs)):
+        ops = np.asarray(out["ops"])[i, :int(out["n_ops"][i])]
+        np.testing.assert_array_equal(ops, host.ops[i])
+        validate_cigar(r, f, ops, expected_dist=int(out["dist"][i]))
+        assert int(out["dist"][i]) >= levenshtein(r, f)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_loop_stops_after_last_failure(backend):
+    """Every lane fails at k before its read ends: the loop stops after the
+    step in which the last lane failed, and each lane's merged partial
+    progress (committed ops, dist, consumption) equals what the exact-shape
+    align_pairs call of the host rescue loop reports."""
+    cfg = _loop_cfg(backend)
+    rng = np.random.default_rng(43)
+    reads, refs = [], []
+    for n, good in ((80, 12), (60, 31), (45, 0), (70, 22)):
+        # the read follows its ref for `good` bases, then is unrelated
+        f = rng.integers(0, 4, n + 10).astype(np.uint8)
+        reads.append(np.concatenate(
+            [f[:good], rng.integers(0, 4, n - good).astype(np.uint8)]))
+        refs.append(f)
+    args = tuple(map(jnp.asarray, _pad_to(reads, refs, cfg, BUCKET)))
+    out = align_pairs_rescued(*args, cfg=cfg, max_read_len=BUCKET,
+                              rescue_rounds=0)
+    failed = np.asarray(out["failed"])
+    assert failed.all()
+    # a lane that failed in window f committed f windows of `stride` reads
+    rcon = np.asarray(out["read_consumed"])
+    assert (rcon % cfg.stride == 0).all()
+    last_failure = int(rcon.max()) // cfg.stride
+    assert int(out["window_steps"]) == last_failure + 1 + 1
+    assert int(out["window_steps"]) < _main(80) + 1
+    exact = align_pairs(*map(jnp.asarray, _pad_to(reads, refs, cfg, 80)),
+                        cfg=cfg, max_read_len=80)
+    for key in ("n_ops", "dist", "failed", "read_consumed", "ref_consumed"):
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(exact[key]), err_msg=key)
+    host = GenASMAligner(cfg, rescue_rounds=0,
+                         rescue_mode="host").align(reads, refs)
+    np.testing.assert_array_equal(failed, host.failed)
+    fcon = np.asarray(out["ref_consumed"])
+    for i, (r, f) in enumerate(zip(reads, refs)):
+        n = int(out["n_ops"][i])
+        ops = np.asarray(out["ops"])[i, :n]
+        np.testing.assert_array_equal(ops, np.asarray(exact["ops"])[i, :n])
+        validate_cigar(r[:rcon[i]], f[:fcon[i]], ops,
+                       expected_dist=int(out["dist"][i]))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_rescued_window_steps_sum_each_rungs_own(backend):
+    """Under the on-device ladder each rung's loop ends at its own last
+    active lane, and window_steps sums the rungs' counts.  Lanes: a clean
+    20 bp read, a 44 bp read with three substitutions in its first window
+    (k = 2 fails it there, k = 4 solves it) and an unrelated decoy."""
+    cfg = _loop_cfg(backend)
+    rng = np.random.default_rng(47)
+    refs = [rng.integers(0, 4, n).astype(np.uint8) for n in (20, 44, 30)]
+    reads = [refs[0].copy(), _subs(refs[1], [2, 6, 10]),
+             rng.integers(0, 4, 30).astype(np.uint8)]
+    args = tuple(map(jnp.asarray,
+                     _pad_to(reads, refs, cfg, BUCKET, rescue_rounds=1)))
+    out = align_pairs_rescued(*args, cfg=cfg, max_read_len=BUCKET,
+                              rescue_rounds=1)
+    assert np.asarray(out["failed"]).tolist() == [False, False, True]
+    assert np.asarray(out["k_used"]).tolist() == [2, 4, 0]
+    # k = 2: the 20 bp read's windows, the others fail in their first;
+    # k = 4 reruns every lane: the 44 bp read's windows
+    rung_steps = [max(_main(20), 1) + 1, max(_main(20), _main(44), 1) + 1]
+    for rung, steps in zip(rescue_schedule(cfg, 1), rung_steps):
+        alone = align_pairs(*args, cfg=rung, max_read_len=BUCKET)
+        assert int(alone["window_steps"]) == steps
+    assert int(out["window_steps"]) == sum(rung_steps)
+    assert int(out["window_steps"]) < 2 * (_main(BUCKET) + 1)
+    host = GenASMAligner(cfg, rescue_rounds=1,
+                         rescue_mode="host").align(reads, refs)
+    np.testing.assert_array_equal(np.asarray(out["dist"]), host.dist)
+    np.testing.assert_array_equal(np.asarray(out["k_used"]), host.k_used)
+    for i in range(2):
+        np.testing.assert_array_equal(
+            np.asarray(out["ops"])[i, :int(out["n_ops"][i])], host.ops[i])
