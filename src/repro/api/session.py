@@ -543,8 +543,9 @@ class AlignSession:
     """
 
     #: legacy stats key -> registry metric name: ``session.stats`` is a
-    #: read-only view building this dict from the obs counters (the
-    #: docs/observability.md catalogue mirrors this table)
+    #: read-only view building this dict from the obs counters, and from
+    #: the per-rung lane-window counters of ``stat_metrics`` (the
+    #: docs/observability.md catalogue mirrors both)
     STAT_METRICS = {
         "dispatches": "session_dispatches_total",
         "lanes": "session_lanes_total",
@@ -571,13 +572,25 @@ class AlignSession:
         # one observability domain per session (registry + tracer on the
         # session clock); 'off' -> the null bundle, zero hot-path cost
         self.obs = resolve_obs(obs, clock=self._clock)
+        #: STAT_METRICS plus, for each rung k of the ladder, the lane-
+        #: windows it ran and those of the reads it solved
+        self._rung_ks = tuple(c.k for c in rescue_schedule(
+            spec.cfg, spec.rescue_rounds))
+        self.stat_metrics = dict(self.STAT_METRICS)
+        for k in self._rung_ks:
+            self.stat_metrics[f"lane_windows_k{k}"] = \
+                f"session_lane_windows_k{k}_total"
+            self.stat_metrics[f"useful_lane_windows_k{k}"] = \
+                f"session_useful_lane_windows_k{k}_total"
         # metric objects are fetched ONCE here; the hot path pays a
         # locked += per event (or a no-op call when obs='off')
         self._m = {k: self.obs.counter(name)
-                   for k, name in self.STAT_METRICS.items()}
+                   for k, name in self.stat_metrics.items()}
         self._n_dispatched = 0     # numbers the dispatches for their spans
-        # the lane-window counters' extra download; none under obs='off'
-        self._count_keys = ("window_steps",) if self.obs.enabled else ()
+        # the lane-window counters' extra downloads (those a step has);
+        # none under obs='off'
+        self._count_keys = (("window_steps", "rung_window_steps")
+                            if self.obs.enabled else ())
         if cache == "shared":
             store = _PROCESS_CACHE
         elif cache == "private":
@@ -1028,8 +1041,8 @@ class AlignSession:
         n = len(d.futures)
         span = self.obs.span
         with span("retire.decode", n=n, dispatch=d.seq):
-            keys = _OUT_KEYS + (("k_used",) if "k_used" in d.out else ()) \
-                + self._count_keys
+            keys = _OUT_KEYS + tuple(k for k in ("k_used",) + self._count_keys
+                                     if k in d.out)
             with span("retire.fetch"):
                 host = transfer.to_host({k: d.out[k] for k in keys})
             with span("retire.records"):
@@ -1043,22 +1056,32 @@ class AlignSession:
                 with span("retire.records"):
                     recs = records_from_state(*state)
             if self._count_keys:
-                self._count_lane_windows(d, host["window_steps"], failed)
+                self._count_lane_windows(d, host, failed, state[2])
             with span("retire.fulfill"):
                 for fut, rec in zip(d.futures, recs):
                     fut._fulfill(rec)
         self._m["retire_wall_s"].inc(self._clock() - t0)
 
-    def _count_lane_windows(self, d: _Dispatch, window_steps, failed):
+    def _count_lane_windows(self, d: _Dispatch, host, failed, k_used):
         """Lane-windows the device ran for `d` (its lanes times the window
-        steps its step reports) against those whose ops are in an answer:
-        a solved real lane needs n_main_windows(read length) + 1 windows,
-        since every window commits W - O read characters.  Rescue rungs
-        add their own lanes x steps in _rescue_compacted."""
-        self._m["lane_windows"].inc(d.lanes * int(window_steps))
-        self._m["useful_lane_windows"].inc(sum(
-            n_main_windows(len(r), self.cfg) + 1
-            for r, bad in zip(d.reads, failed) if not bad))
+        steps its step reports, in total and per rung) against those whose
+        ops are in an answer: a solved real lane needs
+        n_main_windows(read length) + 1 windows, since every window commits
+        W - O read characters, and counts at the rung k_used names.  The
+        on-device ladder reports every rung's steps (``rung_window_steps``);
+        a plain step is the base rung, and bucket-rescue rungs add their
+        own lanes x steps in _rescue_compacted."""
+        self._m["lane_windows"].inc(d.lanes * int(host["window_steps"]))
+        rung_steps = host.get("rung_window_steps", [host["window_steps"]])
+        for k, steps in zip(self._rung_ks, rung_steps):
+            self._m[f"lane_windows_k{k}"].inc(d.lanes * int(steps))
+        useful = dict.fromkeys(self._rung_ks, 0)
+        for r, bad, k in zip(d.reads, failed, k_used):
+            if not bad:
+                useful[int(k)] += n_main_windows(len(r), self.cfg) + 1
+        self._m["useful_lane_windows"].inc(sum(useful.values()))
+        for k, n in useful.items():
+            self._m[f"useful_lane_windows_k{k}"].inc(n)
 
     def _rescue_compacted(self, d, failed, dist, k_used, rcon, fcon,
                           all_ops):
@@ -1088,11 +1111,14 @@ class AlignSession:
                     self._pad_batch(reads, refs, lanes, Lr, Lf))
                 out, _ = exe(*dev)
                 host = transfer.to_host(
-                    {k: out[k] for k in _OUT_KEYS + self._count_keys})
+                    {k: out[k] for k in _OUT_KEYS + self._count_keys
+                     if k in out})
             self._m["rescue_dispatches"].inc()
             self._m["rescue_lanes"].inc(lanes)
             if self._count_keys:
-                self._m["lane_windows"].inc(lanes * int(host["window_steps"]))
+                ran = lanes * int(host["window_steps"])
+                self._m["lane_windows"].inc(ran)
+                self._m[f"lane_windows_k{cfg_r.k}"].inc(ran)
             ok = ~np.asarray(host["failed"])
             for loc, glob in enumerate(todo):
                 if ok[loc]:

@@ -346,9 +346,10 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     refs must be sentinel-padded for the FINAL round's tail width
     (``self_tail_width(rescue_schedule(cfg, rescue_rounds)[-1])``); reads
     need the usual >= W padding.  Returns the align_pairs dict plus k_used
-    (0 where never solved), rounds_run and n_rounds; ``window_steps`` sums
-    the window steps (main windows + tail) of the rounds that ran, each
-    round's loop ending at its own last active lane.
+    (0 where never solved), rounds_run and n_rounds; ``rung_window_steps``
+    (one int32 per rung of the ladder) holds each rung's window steps (main
+    windows + tail, its loop ending at its own last active lane; 0 for a
+    rung that was skipped) and ``window_steps`` is their sum.
 
     `mesh` threads through to every round's align_pairs: the whole ladder
     runs sharded over the pair axes, and the `any(failed)` round gate is a
@@ -368,7 +369,7 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     failed = jnp.ones((B,), bool)
     levels = jnp.int32(0)
     rounds_run = jnp.int32(0)
-    window_steps = jnp.int32(0)
+    rung_steps = []
 
     for rnd, cfg_r in enumerate(cfgs):
         def run_round(cfg_r=cfg_r):
@@ -407,9 +408,12 @@ def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
         failed = failed & out["failed"]
         levels = levels + out["levels_run_total"]
         rounds_run = rounds_run + ran.astype(jnp.int32)
-        window_steps = window_steps + out["window_steps"]  # 0 when skipped
+        rung_steps.append(out["window_steps"])  # 0 when skipped
 
+    rung_window_steps = jnp.stack(rung_steps)
     return {"ops": ops, "n_ops": n_ops, "dist": dist, "failed": failed,
             "k_used": k_used, "read_consumed": rcon, "ref_consumed": fcon,
             "levels_run_total": levels, "rounds_run": rounds_run,
-            "n_rounds": jnp.int32(len(cfgs)), "window_steps": window_steps}
+            "n_rounds": jnp.int32(len(cfgs)),
+            "window_steps": jnp.sum(rung_window_steps),
+            "rung_window_steps": rung_window_steps}

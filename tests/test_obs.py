@@ -286,7 +286,8 @@ def test_obs_off_session_is_a_true_noop():
     with plan(CFG, **PLAN_KW, obs="off") as s:
         assert s.obs is OBS_OFF
         assert all(m is NULL_METRIC for m in s._m.values())
-        assert s.stats == {k: 0 for k in AlignSession.STAT_METRICS}
+        assert s.stats == {k: 0 for k in s.stat_metrics}
+        assert AlignSession.STAT_METRICS.items() <= s.stat_metrics.items()
         s.align(reads, refs)           # warm: compiles outside the window
 
         obs_dir = os.path.dirname(repro.obs.__file__)
@@ -353,7 +354,7 @@ def test_session_and_cache_view_families_match_registry():
     with plan(CFG, **PLAN_KW) as s:
         s.align(reads, refs)
         snap = s.obs.snapshot()
-        for key, name in AlignSession.STAT_METRICS.items():
+        for key, name in s.stat_metrics.items():
             assert s.stats[key] == snap[name], (key, name)
         assert s.stats["requests"] == 6
         assert s.stats["dispatches"] == 2
@@ -447,6 +448,36 @@ def test_lane_window_counters_closed_forms(rescue_mode):
         assert s.stats["lane_windows"] == 0
         assert s.stats["useful_lane_windows"] == 0
 
+
+
+@pytest.mark.parametrize("rescue_mode", ["bucket", "device"])
+def test_per_rung_lane_window_counters_sum_to_the_totals(rescue_mode):
+    """For each rung k of the ladder the session counts the lane-windows
+    that rung ran (lane_windows_k<k>) and those of the reads it solved
+    (useful_lane_windows_k<k>); over the rungs they sum to lane_windows
+    and useful_lane_windows.  The on-device ladder reports each rung's
+    steps (rung_window_steps); bucket rescue counts its compacted rung
+    dispatches at their own k."""
+    reads, refs = _lane_window_pairs()
+    main = lambda n: n_main_windows(n, CFG)  # noqa: E731
+    with plan(CFG, **dict(PLAN_KW, rescue_mode=rescue_mode)) as s:
+        assert [k for k in s.stat_metrics if k.startswith("lane_windows_")] \
+            == ["lane_windows_k2", "lane_windows_k4"]
+        s0 = dict(s.stats)
+        s.align(reads, refs)
+        d = {k: v - s0[k] for k, v in s.stats.items()}
+    for total in ("lane_windows", "useful_lane_windows"):
+        assert d[total] == d[f"{total}_k2"] + d[f"{total}_k4"], total
+    k2 = _steps(main(20), main(30), FIRST, FIRST)
+    assert d["lane_windows_k2"] == 4 * k2
+    if rescue_mode == "bucket":
+        assert d["lane_windows_k4"] == 2 * _steps(main(30), FIRST)
+    else:
+        assert d["lane_windows_k4"] == 4 * _steps(main(20), main(30),
+                                                  main(30), FIRST)
+    # k = 2 solves the two exact pairs, k = 4 the substituted read
+    assert d["useful_lane_windows_k2"] == _windows(20) + _windows(30)
+    assert d["useful_lane_windows_k4"] == _windows(30)
 
 def test_gateway_family_matches_registry():
     clk = FakeClock()

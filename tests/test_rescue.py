@@ -8,8 +8,13 @@ Properties enforced:
     (the per-lane mask never leaks state across lanes),
   * the on-device path performs exactly one upload and one download per
     batch, independent of how many rescue rounds run (the zero
-    per-round-round-trip claim); the host loop pays per executed round.
+    per-round-round-trip claim); the host loop pays per executed round;
+  * the served ladder of the default geometry reaches k = 48 and agrees
+    with the benchmark's plain reference (``bench/reference.py``) there.
 """
+import os
+import sys
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -222,3 +227,48 @@ def test_device_rescue_zero_per_round_roundtrips_fused_backend(corpus):
     # the decoy fails k=2 and k=4, so both ladder rounds execute
     assert s_host.d2h_calls == 2
     assert s_host.h2d_calls == 2
+
+
+def _ont15_pairs(n=6, read_len=200):
+    """Seeded 15%-error pairs with the ONT error split (23:31:46), and one
+    read with a burst of 30 substitutions in its second window: more than
+    k = 24 allows there, so only the k = 48 rung aligns it."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from bench import simulate
+    gen = simulate.genome(20_000, simulate.rng_for(16, 0))
+    pairs = simulate.reads(gen, n, read_len, 0.15, 23, 31, 46,
+                           simulate.rng_for(16, 1))
+    read, ref = pairs[0]
+    rng = simulate.rng_for(16, 2)
+    burst = rng.choice(np.arange(44, 100), 30, replace=False)
+    read = read.copy()
+    read[burst] = (read[burst] + rng.integers(1, 4, 30)) % 4
+    pairs[0] = (read, ref)
+    return pairs
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_served_ladder_reaches_k48_and_matches_the_reference(backend):
+    """plan(rescue_mode='device', rescue_rounds=2) at W=64 O=24 k=12 runs
+    the ladder 12, 24, 48 (interpret-mode kernels for 'pallas_fused'):
+    the burst read is solved at k = 48, and every record equals the plain
+    reference's (dist, k_used, CIGAR ops, consumed lengths)."""
+    from bench import reference
+    from repro.api import plan
+    pairs = _ont15_pairs()
+    want = reference.align([r for r, _ in pairs], [f for _, f in pairs],
+                           reference.Geometry(W=64, O=24, k=12,
+                                              rescue_rounds=2))
+    cfg = AlignerConfig(W=64, O=24, k=12, backend=backend, lane_tile=8)
+    with plan(cfg, rescue_rounds=2, rescue_mode="device",
+              batch_lanes=8) as s:
+        futs = [s.submit(r, f) for r, f in pairs]
+        s.flush()
+        got = [fut.result() for fut in futs]
+    assert got[0]["k_used"] == want[0]["k_used"] == 48
+    assert {w["k_used"] for w in want[1:]} <= {12, 24}
+    for g, w in zip(got, want):
+        for field in ("ok", "dist", "k_used", "cigar", "read_consumed",
+                      "ref_consumed"):
+            assert g[field] == w[field], field
+        np.testing.assert_array_equal(np.asarray(g["ops"]), w["ops"])

@@ -163,26 +163,32 @@ def compiled_kernels(monkeypatch):
     jax.clear_caches()
 
 
-def test_served_step_names_kernels_by_rung(compiled_kernels, one_chip):
-    """The served step (the on-device ladder at rescue_rounds=1, k = 12
-    and 24) compiled for the chip names each kernel by its rung: the
-    device trace's op line shows HLO instruction names, and a ledger
-    breakdown compares them across changes.  The rung's named scope
-    reaches the operators' op_name."""
+@pytest.mark.parametrize("rescue_rounds", [1, 2])
+def test_served_step_names_kernels_by_rung(compiled_kernels, one_chip,
+                                           rescue_rounds):
+    """The served step (the on-device ladder: k = 12 and 24 at
+    rescue_rounds=1, and k = 48 too at 2) compiled for the chip names each
+    kernel by its rung: the device trace's op line shows HLO instruction
+    names, and a ledger breakdown compares them across changes.  The
+    rung's named scope reaches the operators' op_name."""
     import re
     from repro.serve.align_step import make_align_step
     rb = 128                            # two main windows and the tail
-    step = make_align_step(CFG, rb, None, rescue_rounds=1)
+    step = make_align_step(CFG, rb, None, rescue_rounds=rescue_rounds)
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-            for a in bucket_avals(CFG, CFG.lane_tile, rb, rb, 1)]
+            for a in bucket_avals(CFG, CFG.lane_tile, rb, rb, rescue_rounds)]
     text = step.lower(*args).compile().as_text()
     kernels = {m.group(1) for m in re.finditer(
         r"%(\S+)\.\d+ = .*custom_call_target=\"tpu_custom_call\"", text)}
-    assert kernels == {"genasm_tb_k12", "genasm_tb_k24", "genasm_tail_k12",
-                       "genasm_tail_k24"}
+    ks = [c.k for c in rescue_schedule(CFG, rescue_rounds)]
+    assert ks == [12, 24, 48][:rescue_rounds + 1]
+    assert kernels == {f"genasm_{kind}_k{k}" for kind in ("tb", "tail")
+                       for k in ks}
     op_names = re.findall(r'op_name="([^"]*)"', text)
-    assert any("/rung_k24/" in n and "/window_step/" in n
-               and n.endswith("/genasm_tb_k24/pallas_call") for n in op_names)
+    top = ks[-1]
+    assert any(f"/rung_k{top}/" in n and "/window_step/" in n
+               and n.endswith(f"/genasm_tb_k{top}/pallas_call")
+               for n in op_names)
     assert any("/rung_k12/" in n and "/tail_window/" in n
                and n.endswith("/genasm_tail_k12/pallas_call")
                for n in op_names)

@@ -198,7 +198,8 @@ def test_loop_stops_after_last_failure(backend):
 @pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
 def test_rescued_window_steps_sum_each_rungs_own(backend):
     """Under the on-device ladder each rung's loop ends at its own last
-    active lane, and window_steps sums the rungs' counts.  Lanes: a clean
+    active lane: rung_window_steps holds each rung's count and
+    window_steps their sum.  Lanes: a clean
     20 bp read, a 44 bp read with three substitutions in its first window
     (k = 2 fails it there, k = 4 solves it) and an unrelated decoy."""
     cfg = _loop_cfg(backend)
@@ -218,6 +219,7 @@ def test_rescued_window_steps_sum_each_rungs_own(backend):
     for rung, steps in zip(rescue_schedule(cfg, 1), rung_steps):
         alone = align_pairs(*args, cfg=rung, max_read_len=BUCKET)
         assert int(alone["window_steps"]) == steps
+    assert np.asarray(out["rung_window_steps"]).tolist() == rung_steps
     assert int(out["window_steps"]) == sum(rung_steps)
     assert int(out["window_steps"]) < 2 * (_main(BUCKET) + 1)
     host = GenASMAligner(cfg, rescue_rounds=1,
