@@ -589,8 +589,8 @@ class AlignSession:
         self._n_dispatched = 0     # numbers the dispatches for their spans
         # the lane-window counters' extra downloads (those a step has);
         # none under obs='off'
-        self._count_keys = (("window_steps", "rung_window_steps")
-                            if self.obs.enabled else ())
+        self._count_keys = (("window_steps", "rung_window_steps",
+                             "rung_commits") if self.obs.enabled else ())
         if cache == "shared":
             store = _PROCESS_CACHE
         elif cache == "private":
@@ -1065,23 +1065,31 @@ class AlignSession:
     def _count_lane_windows(self, d: _Dispatch, host, failed, k_used):
         """Lane-windows the device ran for `d` (its lanes times the window
         steps its step reports, in total and per rung) against those whose
-        ops are in an answer: a solved real lane needs
-        n_main_windows(read length) + 1 windows, since every window commits
-        W - O read characters, and counts at the rung k_used names.  The
-        on-device ladder reports every rung's steps (``rung_window_steps``);
-        a plain step is the base rung, and bucket-rescue rungs add their
-        own lanes x steps in _rescue_compacted."""
+        ops are in an answer.  The on-device ladder reports the steps in
+        which each rung's kernel ran (``rung_window_steps``) and the
+        windows each lane committed at each rung (``rung_commits``): a
+        solved real lane's commits are useful at their rung.  A plain step
+        is the base rung, and bucket-rescue rungs add their own lanes x
+        steps in _rescue_compacted; there a solved real lane needs
+        n_main_windows(read length) + 1 windows, since every window
+        commits W - O read characters, all at the rung k_used names."""
         self._m["lane_windows"].inc(d.lanes * int(host["window_steps"]))
         rung_steps = host.get("rung_window_steps", [host["window_steps"]])
         for k, steps in zip(self._rung_ks, rung_steps):
             self._m[f"lane_windows_k{k}"].inc(d.lanes * int(steps))
-        useful = dict.fromkeys(self._rung_ks, 0)
-        for r, bad, k in zip(d.reads, failed, k_used):
-            if not bad:
-                useful[int(k)] += n_main_windows(len(r), self.cfg) + 1
+        n = len(d.reads)
+        if "rung_commits" in host:
+            solved = ~np.asarray(failed[:n], bool)
+            per_rung = np.asarray(host["rung_commits"])[:n][solved].sum(0)
+            useful = dict(zip(self._rung_ks, map(int, per_rung)))
+        else:
+            useful = dict.fromkeys(self._rung_ks, 0)
+            for r, bad, k in zip(d.reads, failed, k_used):
+                if not bad:
+                    useful[int(k)] += n_main_windows(len(r), self.cfg) + 1
         self._m["useful_lane_windows"].inc(sum(useful.values()))
-        for k, n in useful.items():
-            self._m[f"useful_lane_windows_k{k}"].inc(n)
+        for k, c in useful.items():
+            self._m[f"useful_lane_windows_k{k}"].inc(c)
 
     def _rescue_compacted(self, d, failed, dist, k_used, rcon, fcon,
                           all_ops):

@@ -4,9 +4,10 @@ failure rescue, host-side padding, and CIGAR decoding.
 Rescue (pairs whose per-window edit distance exceeds cfg.k retried with
 doubled k) runs in one of two modes:
 
-* ``device`` (default) — a single jitted ``align_pairs_rescued`` call: all
-  k-doubling rounds execute on-device under a per-lane mask, so a batch is
-  uploaded once and downloaded once no matter how many rounds run.
+* ``device`` (default) — a single jitted ``align_pairs_rescued`` call: one
+  window loop escalates each failing window up the k-doubling ladder on
+  device, so a batch is uploaded once and downloaded once no matter how
+  many rungs run.
 * ``host`` — the legacy numpy loop (re-pad and re-upload the failed subset
   every round).  Kept as the differential reference: both modes are
   bit-identical per lane (ops, dist, k_used, failed — see

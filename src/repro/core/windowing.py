@@ -10,9 +10,10 @@ ref consumption, repeat.  The final <= W read chars are aligned in a single
 All problems advance in lockstep (read stride is uniform), and the window
 loop ends at the step after which no problem is active: every read has at
 most W characters left or has failed.  The length class's window count is
-only the loop's upper bound.  Problems whose window edit distance exceeds
-k are flagged `failed` (callers may rescue by re-running those pairs with
-a larger k, see core.aligner).
+only the loop's upper bound.  Under the k-doubling rescue ladder a window
+whose edit distance exceeds k escalates to the next rung within the same
+step (``align_pairs_rescued``); problems whose window is unsolved at the
+top rung are flagged `failed`.
 """
 from __future__ import annotations
 
@@ -173,10 +174,251 @@ def _append_ops(buf, off, ops, nops, active):
         buf, pos, ops)
 
 
+def _fused(cfg: AlignerConfig) -> bool:
+    """Whether the windows run the fused DC + traceback Pallas kernels (the
+    DENT band never leaves the chip; 'pallas_gpu' lowers the same kernel
+    bodies through Triton) rather than the jnp DC and traceback."""
+    return cfg.store == "band" and cfg.backend in ("pallas_fused",
+                                                   "pallas_gpu")
+
+
+#: the per-lane fields of a window that a lane commits from its rung
+_COMMIT_KEYS = ("ops", "n_ops", "read_adv", "ref_adv", "cost")
+
+
+def _window_out(tb, max_ops, **extra):
+    """A rung's window as the ladder uses it: the traceback's
+    `_COMMIT_KEYS` fields, its op rows padded to `max_ops` (the top rung's
+    width) so that every rung's rows select into one buffer, and
+    `extra`."""
+    out = {key: tb[key] for key in _COMMIT_KEYS}
+    pad = max_ops - out["ops"].shape[1]
+    out["ops"] = jnp.pad(out["ops"], ((0, 0), (0, pad)),
+                         constant_values=OP_NONE)
+    return dict(out, **extra)
+
+
+def _square_window(pat, txt, cfg: AlignerConfig, max_ops: int, mesh):
+    """One rung's main window on every lane: the committed traceback of
+    the first W - O read characters, plus `solved` and the DC levels run."""
+    W, stride = cfg.W, cfg.stride
+    if _fused(cfg):
+        from ..kernels.ops import default_interpret, genasm_tb_fused_op
+        tb = genasm_tb_fused_op(pat, txt, cfg=cfg, commit_limit=stride,
+                                max_ops=cfg.tb_max_ops,
+                                max_steps=cfg.tb_max_steps,
+                                interpret=default_interpret(cfg.backend),
+                                mesh=mesh)
+        solved, levels = tb["solved"], tb["levels"]
+    else:
+        wfull = jnp.full((pat.shape[0],), W, jnp.int32)
+        res = dc(pat, txt, wfull, wfull, cfg, mesh=mesh)
+        tb = traceback(res.store, pat, txt, wfull, wfull, res.dist,
+                       jnp.int32(stride), cfg=cfg, mode=cfg.store,
+                       max_ops=cfg.tb_max_ops, max_steps=cfg.tb_max_steps)
+        solved, levels = res.solved, res.levels_run
+    return _window_out(tb, max_ops, solved=solved, levels=levels)
+
+
+def _tail_window(refs, ref_pos, pat_t, m_tail, n_rem, cfg: AlignerConfig,
+                 max_ops: int, mesh):
+    """One rung's tail window on every lane: the remaining read (at most W
+    characters) against the remaining reference, end to end, in a text
+    window of W + 4k characters."""
+    W = cfg.W
+    wt = self_tail_width(cfg)
+    n_tail = jnp.clip(n_rem, 0, wt)
+    txt_t = _slice_rev(refs, ref_pos, wt, n_tail)
+    if _fused(cfg):
+        # rectangular-tail fused kernel: the tail's SENE store is walked
+        # on-chip too (bit-identical to the jnp 'and'-store path below)
+        from ..kernels.ops import default_interpret, genasm_tail_fused_op
+        tb = genasm_tail_fused_op(
+            pat_t, txt_t, m_tail, n_tail, cfg=cfg, n_text=wt,
+            commit_limit=2 * (W + wt), max_ops=W + wt,
+            max_steps=W + wt + 4,
+            interpret=default_interpret(cfg.backend), mesh=mesh)
+        solved = tb["solved"]
+    else:
+        res = dc_jmajor(pat_t, txt_t, m_tail, n_tail, k=cfg.k, n=wt,
+                        nw=cfg.nw, store="and")
+        tb = traceback(res.store, pat_t, txt_t, m_tail, n_tail, res.dist,
+                       jnp.int32(2 * (W + wt)), cfg=cfg, mode="and",
+                       max_ops=W + wt, max_steps=W + wt + 4)
+        solved = res.solved
+    return _window_out(tb, max_ops, solved=solved)
+
+
+def _escalate(cfgs, need, run):
+    """Try one window at each rung of the ladder in turn.  The base rung
+    runs on every lane; rung r runs, under ``lax.cond``, only when some
+    lane in `need` is still unsolved below it, and a skipped rung solves
+    nothing.  Each rung's kernels run under the named scope ``rung_k<k>``.
+    `run(r)` returns rung r's `_window_out`: with `solved` and, for a
+    main window, the DC `levels` run.
+
+    Returns (pick, won, ran): each lane's `_COMMIT_KEYS` fields from the
+    first rung that solved it, plus `levels` summed over the rungs that
+    ran; that rung's index per lane (-1: unsolved at the top rung); and
+    per rung, 1 if its kernel ran."""
+    pick, won, ran = None, None, []
+    for r, cfg_r in enumerate(cfgs):
+        with jax.named_scope(f"rung_k{cfg_r.k}"):
+            if r == 0:
+                out, did = run(r), jnp.bool_(True)
+            else:
+                did = jnp.any(need)
+                spec = jax.eval_shape(partial(run, r))
+                out = jax.lax.cond(
+                    did, partial(run, r),
+                    lambda spec=spec: jax.tree_util.tree_map(
+                        lambda s: jnp.zeros(s.shape, s.dtype), spec))
+        win = need & out["solved"]
+        if r == 0:
+            pick = {key: out[key] for key in _COMMIT_KEYS}
+            pick["levels"] = out.get("levels", jnp.int32(0))
+            won = jnp.where(win, 0, -1).astype(jnp.int32)
+        else:
+            for key in _COMMIT_KEYS:
+                sel = win.reshape(win.shape + (1,) * (out[key].ndim - 1))
+                pick[key] = jnp.where(sel, out[key], pick[key])
+            pick["levels"] = pick["levels"] + out.get("levels", 0)
+            won = jnp.where(win, r, won)
+        need = need & ~win
+        ran.append(did.astype(jnp.int32))
+    return pick, won, jnp.stack(ran)
+
+
+def _window_ladder(reads, read_len, refs, ref_len, *, cfgs, max_read_len: int,
+                   mesh):
+    """The window loop under the k-doubling ladder `cfgs` (one rung: plain
+    windowed alignment).  Every window step slices the read and reference
+    windows once and runs the base rung on every lane; a lane whose window
+    the base rung cannot align tries the next rung, and so on, each higher
+    rung's kernel running only in steps where some lane needs it
+    (`_escalate`).  An active lane commits the ops, advance and cost of the
+    first rung that solved its window; a window unsolved at the top rung
+    fails its lane, which keeps its partial progress.  The tail escalates
+    the same way, each rung under its own text width W + 4k and bounds.
+
+    A window whose edit distance d* is at most k aligns the same at every
+    rung >= k (the text window is W characters at every rung, the walk
+    visits levels <= d* only, the DENT band holds every cell within d* of
+    the diagonal and the op and step budgets are not reached), so each
+    lane's result equals a per-read rerun of the whole read at its lowest
+    sufficient rung: the host rescue loop (core.aligner)."""
+    from ..distributed.sharding import constrain_pairs
+    reads, read_len, refs, ref_len = constrain_pairs(
+        mesh, reads, read_len, refs, ref_len)
+    base, top = cfgs[0], cfgs[-1]
+    B = reads.shape[0]
+    W = base.W
+    nm = n_main_windows(max_read_len, base)
+    op_budget = total_op_budget(max_read_len, top)
+    max_ops_w = top.tb_max_ops
+    max_ops_t = W + self_tail_width(top)
+    n_rungs = len(cfgs)
+
+    read_len = jnp.asarray(read_len, jnp.int32)
+    ref_len = jnp.asarray(ref_len, jnp.int32)
+
+    def active_lanes(read_pos, failed):
+        return (read_len - read_pos > W) & ~failed
+
+    def any_active(carry):
+        (step, read_pos, _, _, _, failed, *_), _ = carry
+        return (step < nm) & jnp.any(active_lanes(read_pos, failed))
+
+    def commit(won, ok, rung_hi, commits):
+        """Per-lane bookkeeping of the rung each committed window used."""
+        rung_hi = jnp.where(ok, jnp.maximum(rung_hi, won), rung_hi)
+        commits = commits + jax.nn.one_hot(
+            jnp.where(ok, won, -1), n_rungs, dtype=jnp.int32)
+        return rung_hi, commits
+
+    @jax.named_scope("window_step")
+    def append_main(carry):
+        (step, read_pos, ref_pos, off, dist, failed, rung_hi, commits,
+         rung_steps, levels), buf = carry
+        active = active_lanes(read_pos, failed)
+        wfull = jnp.full((B,), W, jnp.int32)
+        pat = _slice_rev(reads, read_pos, W, wfull)
+        txt = _slice_rev(refs, ref_pos, W, wfull)
+        tb, won, ran = _escalate(
+            cfgs, active,
+            lambda r: _square_window(pat, txt, cfgs[r], max_ops_w, mesh))
+        ok = active & (won >= 0)
+        buf = _append_ops(buf, off, tb["ops"], jnp.where(ok, tb["n_ops"], 0),
+                          ok)
+        rung_hi, commits = commit(won, ok, rung_hi, commits)
+        st = (
+            step + 1,
+            jnp.where(ok, read_pos + tb["read_adv"], read_pos),
+            jnp.where(ok, ref_pos + tb["ref_adv"], ref_pos),
+            jnp.where(ok, off + tb["n_ops"], off),
+            jnp.where(ok, dist + tb["cost"], dist),
+            failed | (active & ~ok),
+            rung_hi, commits, rung_steps + ran,
+            levels + tb["levels"],
+        )
+        return st, buf
+
+    zeros = jnp.zeros((B,), jnp.int32)
+    buf = jnp.full((B, op_budget), OP_NONE, jnp.uint8)
+    state = (jnp.int32(0), zeros, zeros, zeros, zeros,
+             jnp.zeros((B,), bool), zeros,
+             jnp.zeros((B, n_rungs), jnp.int32),
+             jnp.zeros((n_rungs,), jnp.int32), jnp.int32(0))
+    state, buf = jax.lax.while_loop(any_active, append_main, (state, buf))
+    (_, read_pos, ref_pos, off, dist, failed, rung_hi, commits, rung_steps,
+     levels) = state
+
+    # ---- tail window: remaining read (in (O, W]) vs remaining ref, global ----
+    with jax.named_scope("tail_window"):
+        m_tail = jnp.clip(read_len - read_pos, 0, W)
+        n_rem = ref_len - ref_pos
+        pat_t = _slice_rev(reads, read_pos, W, m_tail)
+
+        def run_tail(r):
+            cfg_r = cfgs[r]
+            out = _tail_window(refs, ref_pos, pat_t, m_tail, n_rem, cfg_r,
+                               max_ops_t, mesh)
+            tail_bad = ((n_rem > self_tail_width(cfg_r))
+                        | (n_rem < jnp.maximum(m_tail - 2 * cfg_r.k, 0)))
+            return dict(out, solved=out["solved"] & ~tail_bad)
+
+        tb_t, won_t, ran_t = _escalate(cfgs, ~failed, run_tail)
+        t_ok = ~failed & (won_t >= 0)
+        buf = _append_ops(buf, off, tb_t["ops"],
+                          jnp.where(t_ok, tb_t["n_ops"], 0), t_ok)
+        rung_hi, commits = commit(won_t, t_ok, rung_hi, commits)
+        n_ops = jnp.where(t_ok, off + tb_t["n_ops"], off)
+        dist = jnp.where(t_ok, dist + tb_t["cost"], dist)
+        failed = ~t_ok
+        read_end = jnp.where(t_ok, read_pos + tb_t["read_adv"], read_pos)
+        ref_end = jnp.where(t_ok, ref_pos + tb_t["ref_adv"], ref_pos)
+    rung_steps = rung_steps + ran_t
+
+    ks = jnp.asarray([c.k for c in cfgs], jnp.int32)
+    return {"ops": buf, "n_ops": n_ops, "dist": dist, "failed": failed,
+            "k_used": jnp.where(failed, 0, ks[rung_hi]),
+            "read_consumed": read_end, "ref_consumed": ref_end,
+            "levels_run_total": levels,
+            "rounds_run": jnp.sum((rung_steps > 0).astype(jnp.int32)),
+            "n_rounds": jnp.int32(n_rungs),
+            "window_steps": jnp.sum(rung_steps),
+            "rung_window_steps": rung_steps, "rung_commits": commits}
+
+
+#: the outputs of plain windowed alignment (align_pairs)
+_PLAIN_KEYS = ("ops", "n_ops", "dist", "failed", "read_consumed",
+               "ref_consumed", "levels_run_total", "window_steps")
+
+
 @partial(jax.jit, static_argnames=("cfg", "max_read_len", "mesh"))
 def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
                 max_read_len: int, mesh=None):
-    """Batched windowed alignment.
+    """Batched windowed alignment at one k: the one-rung window ladder.
 
     reads: (B, Lr_pad) uint8 codes, sentinel-padded by >= W past read_len.
     refs:  (B, Lf_pad) uint8 codes, sentinel-padded by >= W+4k past ref_len.
@@ -186,123 +428,17 @@ def align_pairs(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
     ends at the last active lane: once every read has <= W characters left
     or has failed, a step would commit nothing, so none runs;
     ``n_main_windows(max_read_len)`` only bounds it.  The loop body runs
-    under the named scope ``window_step`` and the tail under
-    ``tail_window``.
+    under the named scope ``window_step``, the tail under ``tail_window``
+    and each kernel under ``rung_k<k>``.
 
     `mesh`: shard the pair axis over the mesh's data axes — the Pallas
     dispatches run under shard_map (each device fills/walks its local
     lanes on-chip) and the jnp paths are GSPMD-constrained.  Bit-identical
     to the unsharded run on every output (tests/test_multidevice.py).
     """
-    from ..distributed.sharding import constrain_pairs
-    reads, read_len, refs, ref_len = constrain_pairs(
-        mesh, reads, read_len, refs, ref_len)
-    B = reads.shape[0]
-    W, O, k, stride = cfg.W, cfg.O, cfg.k, cfg.stride
-    nm = n_main_windows(max_read_len, cfg)
-    wt = self_tail_width(cfg)
-    op_budget = total_op_budget(max_read_len, cfg)
-    max_ops_w = cfg.tb_max_ops
-    max_steps_w = cfg.tb_max_steps
-    max_ops_t = W + wt
-    max_steps_t = W + wt + 4
-
-    read_len = jnp.asarray(read_len, jnp.int32)
-    ref_len = jnp.asarray(ref_len, jnp.int32)
-
-    def active_lanes(read_pos, failed):
-        return (read_len - read_pos > W) & ~failed
-
-    def any_active(carry):
-        (step, read_pos, _, _, _, failed, _), _ = carry
-        return (step < nm) & jnp.any(active_lanes(read_pos, failed))
-
-    @jax.named_scope("window_step")
-    def append_main(carry):
-        (step, read_pos, ref_pos, off, dist, failed, levels), buf = carry
-        active = active_lanes(read_pos, failed)
-        wfull = jnp.full((B,), W, jnp.int32)
-        pat = _slice_rev(reads, read_pos, W, wfull)
-        txt = _slice_rev(refs, ref_pos, W, wfull)
-        if cfg.store == "band" and cfg.backend in ("pallas_fused",
-                                                   "pallas_gpu"):
-            # fused kernel: DC + committed traceback in one Pallas call, the
-            # DENT band never leaves the chip — no host-side traceback walk
-            # ('pallas_gpu' lowers the same kernel body through Triton)
-            from ..kernels.ops import default_interpret, genasm_tb_fused_op
-            tb = genasm_tb_fused_op(pat, txt, cfg=cfg, commit_limit=stride,
-                                    max_ops=max_ops_w, max_steps=max_steps_w,
-                                    interpret=default_interpret(cfg.backend),
-                                    mesh=mesh)
-            solved, levels_run = tb["solved"], tb["levels"]
-        else:
-            res = dc(pat, txt, wfull, wfull, cfg, mesh=mesh)
-            tb = traceback(res.store, pat, txt, wfull, wfull,
-                           res.dist, jnp.int32(stride), cfg=cfg,
-                           mode=cfg.store, max_ops=max_ops_w,
-                           max_steps=max_steps_w)
-            solved, levels_run = res.solved, res.levels_run
-        commit = active & solved
-        buf = _append_ops(buf, off, tb["ops"], jnp.where(commit, tb["n_ops"], 0),
-                          commit)
-        st = (
-            step + 1,
-            jnp.where(commit, read_pos + tb["read_adv"], read_pos),
-            jnp.where(commit, ref_pos + tb["ref_adv"], ref_pos),
-            jnp.where(commit, off + tb["n_ops"], off),
-            jnp.where(commit, dist + tb["cost"], dist),
-            failed | (active & ~solved),
-            levels + levels_run,
-        )
-        return st, buf
-
-    buf = jnp.full((B, op_budget), OP_NONE, jnp.uint8)
-    state = (jnp.int32(0), jnp.zeros((B,), jnp.int32),
-             jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-             jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool), jnp.int32(0))
-    state, buf = jax.lax.while_loop(any_active, append_main, (state, buf))
-    steps, read_pos, ref_pos, off, dist, failed, levels = state
-
-    # ---- tail window: remaining read (in (O, W]) vs remaining ref, global ----
-    with jax.named_scope("tail_window"):
-        m_tail = jnp.clip(read_len - read_pos, 0, W)
-        n_rem = ref_len - ref_pos
-        n_tail = jnp.clip(n_rem, 0, wt)
-        tail_bad = (n_rem > wt) | (n_rem < jnp.maximum(m_tail - 2 * k, 0))
-        pat_t = _slice_rev(reads, read_pos, W, m_tail)
-        txt_t = _slice_rev(refs, ref_pos, wt, n_tail)
-        if cfg.store == "band" and cfg.backend in ("pallas_fused",
-                                                   "pallas_gpu"):
-            # rectangular-tail fused kernel: the tail's SENE store is walked
-            # on-chip too, so whole-read alignment never ships DP state to
-            # HBM (bit-identical to the jnp 'and'-store path below)
-            from ..kernels.ops import default_interpret, genasm_tail_fused_op
-            tb_t = genasm_tail_fused_op(
-                pat_t, txt_t, m_tail, n_tail, cfg=cfg, n_text=wt,
-                commit_limit=2 * (W + wt), max_ops=max_ops_t,
-                max_steps=max_steps_t,
-                interpret=default_interpret(cfg.backend), mesh=mesh)
-            solved_t = tb_t["solved"]
-        else:
-            res_t = dc_jmajor(pat_t, txt_t, m_tail, n_tail, k=k, n=wt,
-                              nw=cfg.nw, store="and")
-            tb_t = traceback(res_t.store, pat_t, txt_t, m_tail, n_tail,
-                             res_t.dist, jnp.int32(2 * (W + wt)), cfg=cfg,
-                             mode="and", max_ops=max_ops_t,
-                             max_steps=max_steps_t)
-            solved_t = res_t.solved
-        t_ok = ~failed & ~tail_bad & solved_t
-        buf = _append_ops(buf, off, tb_t["ops"],
-                          jnp.where(t_ok, tb_t["n_ops"], 0), t_ok)
-        n_ops = jnp.where(t_ok, off + tb_t["n_ops"], off)
-        dist = jnp.where(t_ok, dist + tb_t["cost"], dist)
-        failed = failed | tail_bad | ~solved_t
-        read_end = jnp.where(t_ok, read_pos + tb_t["read_adv"], read_pos)
-        ref_end = jnp.where(t_ok, ref_pos + tb_t["ref_adv"], ref_pos)
-
-    return {"ops": buf, "n_ops": n_ops, "dist": dist, "failed": failed,
-            "read_consumed": read_end, "ref_consumed": ref_end,
-            "levels_run_total": levels, "window_steps": steps + 1}
+    out = _window_ladder(reads, read_len, refs, ref_len, cfgs=(cfg,),
+                         max_read_len=max_read_len, mesh=mesh)
+    return {key: out[key] for key in _PLAIN_KEYS}
 
 
 def rescue_schedule(cfg: AlignerConfig, rescue_rounds: int):
@@ -333,87 +469,28 @@ def rescue_schedule(cfg: AlignerConfig, rescue_rounds: int):
 def align_pairs_rescued(reads, read_len, refs, ref_len, *, cfg: AlignerConfig,
                         max_read_len: int, rescue_rounds: int = 2, mesh=None):
     """Multi-round k-doubling rescue, entirely on-device: one compile, zero
-    host round-trips between rounds.
-
-    Round 0 is plain ``align_pairs``; each later round re-runs the whole
-    batch with doubled k under a ``lax.cond`` gate (skipped outright when no
-    lane is still failed), and a per-lane mask freezes already-solved lanes
-    so their ops/dist/k_used never change — bit-identical per lane to the
-    host numpy rescue loop in core.aligner.  Each round runs under the
-    named scope ``rung_k<k>``, so its operators carry the rung in a device
-    trace's ``op_name``.
+    host round-trips between rounds, and one window loop that escalates
+    per window (`_window_ladder`): a rung's kernel runs only in the window
+    steps where some lane's window failed every rung below it.  Per lane
+    bit-identical to the host numpy rescue loop in core.aligner, which
+    reruns each failed read whole at the next k.
 
     refs must be sentinel-padded for the FINAL round's tail width
     (``self_tail_width(rescue_schedule(cfg, rescue_rounds)[-1])``); reads
     need the usual >= W padding.  Returns the align_pairs dict plus k_used
-    (0 where never solved), rounds_run and n_rounds; ``rung_window_steps``
-    (one int32 per rung of the ladder) holds each rung's window steps (main
-    windows + tail, its loop ending at its own last active lane; 0 for a
-    rung that was skipped) and ``window_steps`` is their sum.
+    (the highest rung a solved lane committed a window at; 0 where never
+    solved), rounds_run (rungs whose kernel ran at all) and n_rounds;
+    ``rung_window_steps`` (one int32 per rung of the ladder) holds the
+    window steps in which each rung's kernel ran, + 1 if its tail ran, and
+    ``window_steps`` is their sum; ``rung_commits`` (B, rungs) counts the
+    windows, tail included, each lane committed at each rung.
 
-    `mesh` threads through to every round's align_pairs: the whole ladder
-    runs sharded over the pair axes, and the `any(failed)` round gate is a
-    GLOBAL any (GSPMD reduces it across shards), so a round runs on every
-    device whenever any shard still has a failed lane — exactly the
-    single-device schedule, hence bit-identical results.
+    `mesh` threads through to every rung's kernels: the whole ladder runs
+    sharded over the pair axes, and each rung's `any` gate is a GLOBAL any
+    (GSPMD reduces it across shards), so a rung runs on every device
+    whenever any shard needs it — exactly the single-device schedule,
+    hence bit-identical results.
     """
-    cfgs = rescue_schedule(cfg, rescue_rounds)
-    B = reads.shape[0]
-    budget = total_op_budget(max_read_len, cfgs[-1])
-    ops = jnp.full((B, budget), OP_NONE, jnp.uint8)
-    n_ops = jnp.zeros((B,), jnp.int32)
-    dist = jnp.zeros((B,), jnp.int32)
-    rcon = jnp.zeros((B,), jnp.int32)
-    fcon = jnp.zeros((B,), jnp.int32)
-    k_used = jnp.zeros((B,), jnp.int32)
-    failed = jnp.ones((B,), bool)
-    levels = jnp.int32(0)
-    rounds_run = jnp.int32(0)
-    rung_steps = []
-
-    for rnd, cfg_r in enumerate(cfgs):
-        def run_round(cfg_r=cfg_r):
-            with jax.named_scope(f"rung_k{cfg_r.k}"):
-                return align_pairs(reads, read_len, refs, ref_len, cfg=cfg_r,
-                                   max_read_len=max_read_len, mesh=mesh)
-        if rnd == 0:
-            out = run_round()
-            ran = jnp.bool_(True)
-        else:
-            ran = jnp.any(failed)
-            spec = jax.eval_shape(run_round)
-
-            def skip_round(spec=spec):
-                z = jax.tree_util.tree_map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), spec)
-                z["failed"] = jnp.ones((B,), bool)  # nothing merges
-                return z
-
-            out = jax.lax.cond(ran, run_round, skip_round)
-        newly = failed & ~out["failed"]
-        # final round also merges the partial progress (committed main-window
-        # ops/dist) of still-failed lanes, so rescue_rounds=0 is bit-equal to
-        # plain align_pairs; a skipped final round has no failed lanes.
-        upd = newly
-        if rnd == len(cfgs) - 1:
-            upd = newly | (failed & out["failed"])
-        ops_r = jnp.pad(out["ops"], ((0, 0), (0, budget - out["ops"].shape[1])),
-                        constant_values=OP_NONE)
-        ops = jnp.where(upd[:, None], ops_r, ops)
-        n_ops = jnp.where(upd, out["n_ops"], n_ops)
-        dist = jnp.where(upd, out["dist"], dist)
-        rcon = jnp.where(upd, out["read_consumed"], rcon)
-        fcon = jnp.where(upd, out["ref_consumed"], fcon)
-        k_used = jnp.where(newly, jnp.int32(cfg_r.k), k_used)
-        failed = failed & out["failed"]
-        levels = levels + out["levels_run_total"]
-        rounds_run = rounds_run + ran.astype(jnp.int32)
-        rung_steps.append(out["window_steps"])  # 0 when skipped
-
-    rung_window_steps = jnp.stack(rung_steps)
-    return {"ops": ops, "n_ops": n_ops, "dist": dist, "failed": failed,
-            "k_used": k_used, "read_consumed": rcon, "ref_consumed": fcon,
-            "levels_run_total": levels, "rounds_run": rounds_run,
-            "n_rounds": jnp.int32(len(cfgs)),
-            "window_steps": jnp.sum(rung_window_steps),
-            "rung_window_steps": rung_window_steps}
+    return _window_ladder(reads, read_len, refs, ref_len,
+                          cfgs=rescue_schedule(cfg, rescue_rounds),
+                          max_read_len=max_read_len, mesh=mesh)

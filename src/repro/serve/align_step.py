@@ -74,7 +74,7 @@ def make_align_step(cfg: AlignerConfig, max_read_len: int, mesh,
     sum_sh = {"n_failed": rep, "total_edits": rep, "total_ops": rep}
     if rescue_rounds is not None:
         out_lanes = dict(out_lanes, k_used=vsh, rounds_run=rep, n_rounds=rep,
-                         rung_window_steps=rep)
+                         rung_window_steps=rep, rung_commits=bsh)
         sum_sh = dict(sum_sh, n_rescued=rep, rounds_run=rep)
     return jax.jit(fn, in_shardings=(bsh, vsh, bsh, vsh),
                    out_shardings=(out_lanes, sum_sh))

@@ -384,11 +384,20 @@ def _windows(read_len):
 
 
 def _steps(*lane_steps):
-    """Window steps one rung of a dispatch runs: its loop ends at the last
-    active lane, then the tail.  `lane_steps`: each lane's main steps —
-    n_main_windows(read length) for a lane the rung solves, the index of
-    the window it fails in + 1 for one it fails."""
+    """Window steps the base rung of a dispatch runs (a plain step, or the
+    on-device ladder, whose base rung runs every step): the loop ends at
+    the last active lane, then the tail.  `lane_steps`: each lane's main
+    steps — n_main_windows(read length) for a lane the step solves, the
+    index of the window it fails in (at the step's top rung) + 1 for one
+    it fails."""
     return max(lane_steps) + 1
+
+
+def _escalated(steps, tail=False):
+    """Window steps a higher rung of the on-device ladder runs: the steps
+    in which some lane's window failed every rung below it, + 1 if some
+    lane's tail did."""
+    return steps + int(tail)
 
 
 FIRST = 1                               # a lane failed in its first window
@@ -427,19 +436,22 @@ def test_lane_window_counters_closed_forms(rescue_mode):
                         [refs[0], refs[3], refs[3]])
         assert short.failed.tolist() == [False, True, True]
         lw_short = s.stats["lane_windows"] - s1["lane_windows"]
-    # k = 2 solves the exact pairs and fails the substituted read and the
-    # decoy in their first window
-    k2 = _steps(main(20), main(30), FIRST, FIRST)
     if rescue_mode == "bucket":
-        # the k = 4 rung compacts the two failed lanes into a 2-lane class
-        # and solves the substituted read; the decoy fails again at once
-        assert lw == 4 * k2 + 2 * _steps(main(30), FIRST)
+        # k = 2 solves the exact pairs and fails the substituted read and
+        # the decoy in their first window; the k = 4 rung compacts the two
+        # failed lanes into a 2-lane class and solves the substituted read;
+        # the decoy fails again at once
+        assert lw == 4 * _steps(main(20), main(30), FIRST, FIRST) \
+            + 2 * _steps(main(30), FIRST)
         # the two decoys alone make the k = 4 rung: both fail at once
         assert lw_short == 4 * _steps(main(20), FIRST) + 2 * _steps(FIRST)
     else:
-        # the on-device ladder reruns every lane at k = 4
-        assert lw == 4 * (k2 + _steps(main(20), main(30), main(30), FIRST))
-        assert lw_short == 4 * 2 * _steps(main(20), FIRST)
+        # the on-device ladder runs k = 2 on every step until the
+        # substituted read is done, and k = 4 in the first step only,
+        # where the substituted read and the decoy failed at k = 2
+        assert lw == 4 * (_steps(main(20), main(30), main(30), FIRST)
+                          + _escalated(1))
+        assert lw_short == 4 * (_steps(main(20), FIRST) + _escalated(1))
     assert _steps(main(20), FIRST) < _windows(32)
     # the rung adds only the lane it solved; the decoy adds nothing
     assert useful == _windows(20) + 2 * _windows(30)
@@ -453,11 +465,13 @@ def test_lane_window_counters_closed_forms(rescue_mode):
 @pytest.mark.parametrize("rescue_mode", ["bucket", "device"])
 def test_per_rung_lane_window_counters_sum_to_the_totals(rescue_mode):
     """For each rung k of the ladder the session counts the lane-windows
-    that rung ran (lane_windows_k<k>) and those of the reads it solved
-    (useful_lane_windows_k<k>); over the rungs they sum to lane_windows
-    and useful_lane_windows.  The on-device ladder reports each rung's
-    steps (rung_window_steps); bucket rescue counts its compacted rung
-    dispatches at their own k."""
+    that rung ran (lane_windows_k<k>) and those whose ops it committed to
+    an answer (useful_lane_windows_k<k>); over the rungs they sum to
+    lane_windows and useful_lane_windows, and no rung's useful count
+    exceeds its lane-windows.  The on-device ladder reports the steps each
+    rung ran (rung_window_steps) and each lane's commits per rung
+    (rung_commits); bucket rescue reruns a failed read whole, so its
+    compacted rung dispatches count at their own k."""
     reads, refs = _lane_window_pairs()
     main = lambda n: n_main_windows(n, CFG)  # noqa: E731
     with plan(CFG, **dict(PLAN_KW, rescue_mode=rescue_mode)) as s:
@@ -468,16 +482,57 @@ def test_per_rung_lane_window_counters_sum_to_the_totals(rescue_mode):
         d = {k: v - s0[k] for k, v in s.stats.items()}
     for total in ("lane_windows", "useful_lane_windows"):
         assert d[total] == d[f"{total}_k2"] + d[f"{total}_k4"], total
-    k2 = _steps(main(20), main(30), FIRST, FIRST)
-    assert d["lane_windows_k2"] == 4 * k2
+    for k in (2, 4):
+        assert d[f"useful_lane_windows_k{k}"] <= d[f"lane_windows_k{k}"]
     if rescue_mode == "bucket":
+        assert d["lane_windows_k2"] == 4 * _steps(main(20), main(30), FIRST,
+                                                  FIRST)
         assert d["lane_windows_k4"] == 2 * _steps(main(30), FIRST)
+        # k = 2 solves the two exact pairs, k = 4 the substituted read
+        assert d["useful_lane_windows_k2"] == _windows(20) + _windows(30)
+        assert d["useful_lane_windows_k4"] == _windows(30)
     else:
-        assert d["lane_windows_k4"] == 4 * _steps(main(20), main(30),
+        assert d["lane_windows_k2"] == 4 * _steps(main(20), main(30),
                                                   main(30), FIRST)
-    # k = 2 solves the two exact pairs, k = 4 the substituted read
-    assert d["useful_lane_windows_k2"] == _windows(20) + _windows(30)
-    assert d["useful_lane_windows_k4"] == _windows(30)
+        assert d["lane_windows_k4"] == 4 * _escalated(1)
+        # k = 4 commits the substituted read's first window, k = 2 every
+        # other window of the three solved reads
+        assert d["useful_lane_windows_k2"] == \
+            _windows(20) + 2 * _windows(30) - 1
+        assert d["useful_lane_windows_k4"] == 1
+
+def test_three_rung_ladder_counts_escalated_windows():
+    """On the ladder 2, 4, 8 the k = 4 and k = 8 rungs run only the window
+    step in which some lane needs them, and count as useful only the
+    windows they committed: per rung, useful <= lane-windows, and the
+    per-rung counts sum to the totals.  Window j covers read
+    [10j, 10j + 16), so substitutions in [26, 30] lie in window 2 alone
+    (and one in window 3): three give d = 3 (k = 4), five d = 5 (k = 8).
+    Three real pairs in a 4-lane class; the padding lane repeats the last
+    pair and counts in lane_windows only."""
+    rng = np.random.default_rng(11)
+    refs = [rng.integers(0, 4, 60).astype(np.uint8) for _ in range(3)]
+    reads = [refs[0].copy(), refs[1].copy(), refs[2].copy()]
+    reads[1][[26, 27, 29]] = (reads[1][[26, 27, 29]] + 1) % 4
+    reads[2][26:31] = (reads[2][26:31] + 1) % 4
+    with plan(CFG, rescue_rounds=2, rescue_mode="device",
+              batch_lanes=4) as s:
+        res = s.align(reads, refs)
+        d = dict(s.stats)
+    assert res.k_used.tolist() == [2, 4, 8]
+    assert d["lanes"] == 4 and d["pad_lanes"] == 1
+    w = _windows(60)
+    assert d["lane_windows_k2"] == 4 * _steps(n_main_windows(60, CFG))
+    assert d["lane_windows_k4"] == 4 * _escalated(1)
+    assert d["lane_windows_k8"] == 4 * _escalated(1)
+    assert [d[f"useful_lane_windows_k{k}"] for k in (2, 4, 8)] == \
+        [3 * w - 2, 1, 1]
+    for total in ("lane_windows", "useful_lane_windows"):
+        assert d[total] == sum(d[f"{total}_k{k}"] for k in (2, 4, 8))
+    for k in (2, 4, 8):
+        assert d[f"useful_lane_windows_k{k}"] <= d[f"lane_windows_k{k}"]
+    assert d["useful_lane_windows"] == 3 * w
+
 
 def test_gateway_family_matches_registry():
     clk = FakeClock()

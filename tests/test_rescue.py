@@ -1,11 +1,14 @@
-"""Rescue semantics: the on-device masked k-doubling loop vs the host loop.
+"""Rescue semantics: the on-device k-doubling ladder vs the host loop.
 
 Properties enforced:
   * rescue_rounds=0 is exactly plain align_pairs (plus k_used bookkeeping),
   * k_used is minimal on the k-doubling ladder (the previous rung fails),
   * failed / k_used / ops agree between host-loop and on-device rescue,
+    which escalates per window, wherever a lane's windows escalate (one
+    window, two far apart, the tail alone, failure at the top rung) and
+    with padding lanes,
   * lanes are independent: permuting the batch permutes the results
-    (the per-lane mask never leaks state across lanes),
+    (escalating one lane's window never leaks state across lanes),
   * the on-device path performs exactly one upload and one download per
     batch, independent of how many rescue rounds run (the zero
     per-round-round-trip claim); the host loop pays per executed round;
@@ -24,8 +27,8 @@ from repro.core.aligner import GenASMAligner
 from repro.core.config import AlignerConfig
 from repro.core.oracle import validate_cigar
 from repro.core.windowing import (SENTINEL_READ, SENTINEL_REF, align_pairs,
-                                  align_pairs_rescued, rescue_schedule,
-                                  self_tail_width)
+                                  align_pairs_rescued, n_main_windows,
+                                  rescue_schedule, self_tail_width)
 
 CFG = AlignerConfig(W=16, O=6, k=2)
 ROUNDS = 2                                     # ladder [2, 4, 8]
@@ -176,8 +179,7 @@ def test_session_bucket_rescue_bit_identical_to_host_loop(corpus, host_res):
     """The rescue-efficiency item (ROADMAP): repro.api.AlignSession's
     'bucket' rescue gathers still-failed lanes and compacts them into the
     next-smaller length/lane bucket per k-doubling rung — solved lanes'
-    windows are never recomputed (unlike the on-device ladder, which
-    re-runs the whole batch under a mask) and shapes stay bucket-stable
+    windows are never recomputed and shapes stay bucket-stable
     (unlike the host loop, which re-traces ragged subsets).  Must be
     bit-identical per lane to rescue_mode='host'."""
     from repro.api import plan
@@ -272,3 +274,108 @@ def test_served_ladder_reaches_k48_and_matches_the_reference(backend):
                       "ref_consumed"):
             assert g[field] == w[field], field
         np.testing.assert_array_equal(np.asarray(g["ops"]), w["ops"])
+
+
+def _subs(seq, at):
+    out = seq.copy()
+    out[at] = (out[at] + 1) % 4
+    return out
+
+
+def _escalation_pairs():
+    """Pairs for the ladder 2, 4, 8 at W=16 O=6, each escalating in a known
+    place.  Window j covers read [10j, 10j + 16), so a substitution in
+    [10j + 6, 10j + 10) lies in window j alone, and one past the last
+    main window's end lies in the tail alone.  Returns (reads, refs, the
+    rung_commits row each lane should report; None for a failed lane)."""
+    rng = np.random.default_rng(17)
+    mk = lambda n: rng.integers(0, 4, n).astype(np.uint8)  # noqa: E731
+    cases = []
+    f = mk(60)                                  # clean
+    cases.append((f.copy(), f, [6, 0, 0]))
+    f = mk(60)                                  # window 2 needs k = 4
+    cases.append((_subs(f, [26, 27, 29]), f, [5, 1, 0]))
+    f = mk(90)                                  # windows 2 and 6 need k = 4
+    cases.append((_subs(f, [26, 27, 29, 66, 67, 69]), f, [7, 2, 0]))
+    f = mk(60)                                  # window 2 needs k = 8
+    cases.append((_subs(f, [26, 27, 28, 29, 30]), f, [5, 0, 1]))
+    f = mk(36)                                  # the tail alone needs k = 4
+    cases.append((_subs(f, [28, 31, 34]), f, [2, 1, 0]))
+    # five bases inserted past the last main window: the tail is shorter
+    # than m - 2k allows at k = 2, holds 5 edits at k = 4, solves at k = 8
+    f = mk(50)
+    cases.append((np.concatenate([f[:46], mk(5), f[46:]]), f, [4, 0, 1]))
+    # fails at the top rung mid-read: follows its ref for 25 bases
+    f = mk(70)
+    cases.append((np.concatenate([f[:25], mk(45)]), f, None))
+    # the reference runs on 60 bases past the read: the tail's text is
+    # wider than W + 4k at every rung, so the lane fails after its windows
+    f = mk(100)
+    cases.append((f[:40].copy(), f, None))
+    reads, refs, commits = zip(*cases)
+    return list(reads), list(refs), list(commits)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_per_window_escalation_bit_identical_to_host_loop(backend):
+    """The on-device ladder escalates the failing window, not the read:
+    each lane commits every window at the first rung that solves it and
+    ends up bit-identical to the host loop, which reruns the whole read at
+    each k.  Lanes need a higher rung in one window, in two windows far
+    apart, in the tail alone (through the tail's m - 2k bound too), or fail
+    at the top rung mid-read or in a tail wider than W + 4k; a failed
+    lane keeps the partial progress the top rung alone would report."""
+    cfg = CFG.replace(backend=backend, lane_tile=8)
+    reads, refs, commits = _escalation_pairs()
+    args, max_r = _pad_batch(reads, refs, cfg, ROUNDS)
+    out = align_pairs_rescued(*args, cfg=cfg, max_read_len=max_r,
+                              rescue_rounds=ROUNDS)
+    host = GenASMAligner(cfg, rescue_rounds=ROUNDS,
+                         rescue_mode="host").align(reads, refs)
+    failed = np.asarray(out["failed"])
+    np.testing.assert_array_equal(failed, host.failed)
+    assert failed.tolist() == [c is None for c in commits]
+    np.testing.assert_array_equal(np.asarray(out["k_used"]), host.k_used)
+    assert np.asarray(out["k_used"]).tolist() == [2, 4, 4, 8, 4, 8, 0, 0]
+    rc = np.asarray(out["rung_commits"])
+    ops = np.asarray(out["ops"])
+    for i in np.flatnonzero(~failed):
+        assert rc[i].tolist() == commits[i]
+        assert rc[i].sum() == n_main_windows(len(reads[i]), cfg) + 1
+        assert int(out["dist"][i]) == host.dist[i]
+        assert int(out["read_consumed"][i]) == host.read_consumed[i]
+        assert int(out["ref_consumed"][i]) == host.ref_consumed[i]
+        np.testing.assert_array_equal(ops[i, :int(out["n_ops"][i])],
+                                      host.ops[i])
+    top = align_pairs(*args, cfg=rescue_schedule(cfg, ROUNDS)[-1],
+                      max_read_len=max_r)
+    for i in np.flatnonzero(failed):
+        for key in ("n_ops", "dist", "read_consumed", "ref_consumed"):
+            assert int(out[key][i]) == int(top[key][i]), key
+        n = int(out["n_ops"][i])
+        assert n > 0                           # windows were committed
+        np.testing.assert_array_equal(ops[i], np.asarray(top["ops"])[i])
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_served_ladder_with_padding_lanes_matches_host_loop(backend):
+    """The served on-device ladder over lane classes with padding lanes
+    (the pairs fall into several length buckets, none filling its class):
+    every record equals the host loop's."""
+    from repro.api import plan
+    cfg = CFG.replace(backend=backend, lane_tile=8)
+    reads, refs, _ = _escalation_pairs()
+    host = GenASMAligner(cfg, rescue_rounds=ROUNDS,
+                         rescue_mode="host").align(reads, refs)
+    with plan(cfg, rescue_rounds=ROUNDS, rescue_mode="device",
+              batch_lanes=16, cache="private") as s:
+        res = s.align(reads, refs)
+        assert s.stats["pad_lanes"] > 0
+    np.testing.assert_array_equal(res.failed, host.failed)
+    np.testing.assert_array_equal(res.k_used, host.k_used)
+    np.testing.assert_array_equal(res.dist, host.dist)
+    np.testing.assert_array_equal(res.read_consumed, host.read_consumed)
+    np.testing.assert_array_equal(res.ref_consumed, host.ref_consumed)
+    assert res.cigars == host.cigars
+    for a, b in zip(res.ops, host.ops):
+        np.testing.assert_array_equal(a, b)

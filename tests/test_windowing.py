@@ -197,11 +197,12 @@ def test_loop_stops_after_last_failure(backend):
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
 def test_rescued_window_steps_sum_each_rungs_own(backend):
-    """Under the on-device ladder each rung's loop ends at its own last
-    active lane: rung_window_steps holds each rung's count and
-    window_steps their sum.  Lanes: a clean
-    20 bp read, a 44 bp read with three substitutions in its first window
-    (k = 2 fails it there, k = 4 solves it) and an unrelated decoy."""
+    """Under the on-device ladder the base rung runs every window step and
+    its tail, and a higher rung only the steps in which some lane's window
+    failed below it: rung_window_steps holds each rung's count and
+    window_steps their sum.  Lanes: a clean 20 bp read, a 44 bp read with
+    three substitutions in its first window (k = 2 fails it there, k = 4
+    solves it) and an unrelated decoy (fails its first window at both)."""
     cfg = _loop_cfg(backend)
     rng = np.random.default_rng(47)
     refs = [rng.integers(0, 4, n).astype(np.uint8) for n in (20, 44, 30)]
@@ -213,15 +214,16 @@ def test_rescued_window_steps_sum_each_rungs_own(backend):
                               rescue_rounds=1)
     assert np.asarray(out["failed"]).tolist() == [False, False, True]
     assert np.asarray(out["k_used"]).tolist() == [2, 4, 0]
-    # k = 2: the 20 bp read's windows, the others fail in their first;
-    # k = 4 reruns every lane: the 44 bp read's windows
-    rung_steps = [max(_main(20), 1) + 1, max(_main(20), _main(44), 1) + 1]
-    for rung, steps in zip(rescue_schedule(cfg, 1), rung_steps):
-        alone = align_pairs(*args, cfg=rung, max_read_len=BUCKET)
-        assert int(alone["window_steps"]) == steps
+    # k = 2: every step to the 44 bp read's last window, and the tail;
+    # k = 4: the first step only, where the 44 bp read and the decoy
+    # failed at k = 2; no tail needed it
+    rung_steps = [max(_main(20), _main(44), 1) + 1, 1]
     assert np.asarray(out["rung_window_steps"]).tolist() == rung_steps
     assert int(out["window_steps"]) == sum(rung_steps)
-    assert int(out["window_steps"]) < 2 * (_main(BUCKET) + 1)
+    assert int(out["window_steps"]) < _main(BUCKET) + 1
+    # the 44 bp read commits its first window at k = 4, the rest at k = 2
+    assert np.asarray(out["rung_commits"])[:2].tolist() == [
+        [_main(20) + 1, 0], [_main(44), 1]]
     host = GenASMAligner(cfg, rescue_rounds=1,
                          rescue_mode="host").align(reads, refs)
     np.testing.assert_array_equal(np.asarray(out["dist"]), host.dist)
@@ -229,3 +231,39 @@ def test_rescued_window_steps_sum_each_rungs_own(backend):
     for i in range(2):
         np.testing.assert_array_equal(
             np.asarray(out["ops"])[i, :int(out["n_ops"][i])], host.ops[i])
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_top_rung_runs_only_the_steps_that_need_it(backend):
+    """Closed form of rung_window_steps on the ladder 2, 4, 8: one window
+    of one read needs k = 8, so the k = 8 rung runs one step (the k = 4
+    rung that step too, where it fails), plus one more at k = 4 when a
+    second read's tail alone needs k = 4.  Window j covers read
+    [10j, 10j + 16): five substitutions at 26..30 put d = 5 in window 2
+    and one substitution in window 3."""
+    cfg = _loop_cfg(backend)
+    rng = np.random.default_rng(53)
+    refs = [rng.integers(0, 4, n).astype(np.uint8) for n in (60, 60, 36)]
+    burst = _subs(refs[1], np.arange(26, 31))
+    base_steps = max(_main(60), _main(36)) + 1
+    # the third read clean, then with three substitutions past its last
+    # main window (it ends at 26): same shapes, so one compile
+    for tail_read, k3, rung_steps in (
+            (refs[2].copy(), 2, [base_steps, 1, 1]),
+            (_subs(refs[2], [28, 31, 34]), 4, [base_steps, 1 + 1, 1])):
+        reads = [refs[0].copy(), burst, tail_read]
+        args = tuple(map(jnp.asarray,
+                         _pad_to(reads, refs, cfg, BUCKET, rescue_rounds=2)))
+        out = align_pairs_rescued(*args, cfg=cfg, max_read_len=BUCKET,
+                                  rescue_rounds=2)
+        assert not np.asarray(out["failed"]).any()
+        assert np.asarray(out["k_used"]).tolist() == [2, 8, k3]
+        assert np.asarray(out["rung_window_steps"]).tolist() == rung_steps
+        assert int(out["window_steps"]) == sum(rung_steps)
+        host = GenASMAligner(cfg, rescue_rounds=2,
+                             rescue_mode="host").align(reads, refs)
+        np.testing.assert_array_equal(np.asarray(out["dist"]), host.dist)
+        np.testing.assert_array_equal(np.asarray(out["k_used"]), host.k_used)
+        for i in range(3):
+            np.testing.assert_array_equal(
+                np.asarray(out["ops"])[i, :int(out["n_ops"][i])], host.ops[i])
